@@ -184,8 +184,7 @@ def bench_gain(quick: bool = False, out=sys.stdout,
     for k in ks:
         part = refine.pad_part(rng.integers(0, k, hg.n).astype(np.int32),
                                hga.n_pad)
-        path = ops.gain_path(hga.m_pad, k,
-                             incidence=hga.incident is not None)
+        path = ops.gain_path(k, incidence=hga.incident is not None)
         t_ref = timeit(lambda: metrics.gain_matrix_jit(
             hga, part, k, assemble="segsum"))
         t_new = timeit(lambda: metrics.gain_matrix_jit(hga, part, k))
@@ -222,7 +221,7 @@ def _smoke_gain_paths(out=sys.stdout):
     import jax.numpy as jnp
 
     results = {}
-    for path in ("segsum", "compact", "table", "stream"):
+    for path in ("segsum", "compact", "stream"):
         os.environ["REPRO_GAIN_PATH"] = path
         jax.clear_caches()
         from repro.core import metrics, refine

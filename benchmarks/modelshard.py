@@ -177,14 +177,32 @@ def _rows_subprocess(n: int, m: int, alpha: int, max_iters: int,
 def bench_modelshard(smoke: bool = False, out=sys.stdout,
                      json_path: str | None = "BENCH_modelshard.json"):
     """Emit BENCH_modelshard.json (schema: docs/reference.md)."""
+    import jax
     alpha, max_iters = (2, 1) if smoke else (4, 2)
     budget = 45 * 1024 * 1024   # between ~54.5 MB 1-way and ~37.7 MB 2-way
-    res = _rows_subprocess(N_GIANT, M_GIANT, alpha, max_iters, budget,
-                           out=out)
+    backend = jax.default_backend()
+    forced = local = None
+    if backend == "cpu":
+        res = forced = _rows_subprocess(N_GIANT, M_GIANT, alpha, max_iters,
+                                        budget, out=out)
+    else:
+        # this process holds the devices: a child could not reach them,
+        # and forced host-CPU rows would pass for device rows
+        print(f"# {backend} backend: forced-host-device rows skipped",
+              file=out)
+        if len(jax.local_devices()) < 2:
+            raise SystemExit("modelshard needs >= 2 local devices for a "
+                             "model axis of 2")
+        os.environ["REPRO_POP_MESH_MODEL"] = "2"
+        os.environ["REPRO_DEVICE_MEM_BUDGET"] = str(budget)
+        os.environ.pop("REPRO_POP_SHARD", None)
+        os.environ.pop("REPRO_MODEL_SHARD", None)
+        res = local = measure_rows(N_GIANT, M_GIANT, alpha=alpha,
+                                   max_iters=max_iters, out=out)
     record = {
         "bench": "modelshard",
         "budget_bytes": budget,
-        "forced": res,
+        "forced": forced,
         "note": ("unsharded = replicated structure on every device "
                  "(trips REPRO_DEVICE_MEM_BUDGET, the artificial HBM "
                  "stand-in on forced host devices); model-sharded = pin "
@@ -198,6 +216,8 @@ def bench_modelshard(smoke: bool = False, out=sys.stdout,
                  "wall_s tracks dispatch cost, not a speedup "
                  "(docs/reference.md caveats)."),
     }
+    if local is not None:
+        record["local"] = local
     if json_path:
         with open(json_path, "w") as f:
             json.dump(record, f, indent=2)

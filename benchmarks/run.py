@@ -20,6 +20,9 @@ def main() -> None:
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
 
+    from repro.env import enable_compile_cache
+    enable_compile_cache()
+
     from benchmarks import titan23, ispd98, jumping, largek, kernels_bench
     from benchmarks import roofline
 

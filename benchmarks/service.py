@@ -149,8 +149,15 @@ def bench_service(smoke: bool = False, out=sys.stdout,
         nreq, loads, scale, slots = 12, (1.0, 4.0), 1.0, 4
     ndev = len(jax.local_devices())
     local = measure_rows(nreq, loads, scale, slots=slots, out=out)
-    other = 8 if ndev == 1 else 1
-    forced = _rows_subprocess(other, nreq, loads, scale, slots, out=out)
+    forced = None
+    if jax.default_backend() == "cpu":
+        other = 8 if ndev == 1 else 1
+        forced = _rows_subprocess(other, nreq, loads, scale, slots, out=out)
+    else:
+        # this process holds the device: a child could not reach it, and
+        # forced host-CPU rows would pass for device rows
+        print(f"# {jax.default_backend()} backend: forced-host-device "
+              "rows skipped", file=out)
     single = local if local["devices"] == 1 else forced
     multi = forced if single is local else local
     record = {
@@ -175,8 +182,12 @@ def bench_service(smoke: bool = False, out=sys.stdout,
         with open(json_path, "w") as f:
             json.dump(record, f, indent=2)
             f.write("\n")
-        print(f"# wrote {json_path} (single={single['devices']}d, "
-              f"multi={multi['devices']}d, cuts_equal=True)", file=out)
+        devices = ", ".join(f"{label}={rows['devices']}d"
+                            for label, rows in (("single", single),
+                                                ("multi", multi))
+                            if rows is not None)
+        print(f"# wrote {json_path} ({devices}, cuts_equal=True)",
+              file=out)
     return record
 
 

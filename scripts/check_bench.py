@@ -20,6 +20,13 @@ Two modes:
 
 Parity-flag paths use `.` for dict descent and `[*]` for "every list
 element" (`sweep[*].exact` = the `exact` bit of every sweep row).
+
+Device blocks (`device_blocks` below) hold the rows of one device
+setting, each recording its `backend`.  On an accelerator the writer
+cannot start the forced-host-device child, so the block that child
+would fill is `null` (or, if optional, absent): that is accepted only
+when another block of the same record names a non-CPU backend, and the
+parity paths under the missing block are skipped.
 """
 from __future__ import annotations
 
@@ -32,7 +39,8 @@ ROOT = Path(__file__).resolve().parent.parent
 
 # filename -> {required: top-level keys that must be present,
 #              optional: additionally allowed top-level keys,
-#              parity: dotted flag paths that must be truthy}
+#              parity: dotted flag paths that must be truthy,
+#              device_blocks: row blocks that may be missing on a chip}
 SCHEMAS = {
     "BENCH_population.json": {
         "required": {"alpha", "batched_wall_s", "bench", "cuts_equal",
@@ -72,6 +80,7 @@ SCHEMAS = {
         "optional": set(),
         "parity": ["cuts_equal", "single_device.rows[*].cuts_equal",
                    "multi_device.rows[*].cuts_equal"],
+        "device_blocks": {"single_device", "multi_device"},
     },
     "BENCH_robustness.json": {
         "required": {"alpha", "backend", "baseline_makespan_s", "bench",
@@ -82,8 +91,10 @@ SCHEMAS = {
     },
     "BENCH_modelshard.json": {
         "required": {"bench", "budget_bytes", "forced", "note"},
-        "optional": set(),
-        "parity": ["forced.parity_gate.bit_equal"],
+        "optional": {"local"},   # rows measured on >= 2 local devices
+        "parity": ["forced.parity_gate.bit_equal",
+                   "local.parity_gate.bit_equal"],
+        "device_blocks": {"forced", "local"},
     },
     "BENCH_incremental.json": {
         "required": {"alpha", "bench", "drift_magnitude", "k", "lp_iters",
@@ -94,6 +105,7 @@ SCHEMAS = {
                    "multi_device.rows[*].migration_within_budget",
                    "single_device.summary.all_within_budget",
                    "multi_device.summary.all_within_budget"],
+        "device_blocks": {"single_device", "multi_device"},
     },
     "BENCH_sched.json": {
         "required": {"bench", "note", "policy", "rows", "seed", "smoke",
@@ -141,6 +153,26 @@ def _flag_parts(path: str):
     return parts
 
 
+def _missing_device_blocks(data: dict, schema: dict, name: str,
+                           errors: list) -> set:
+    """Device blocks that are null or absent, checked against the
+    record's backends (see the module docstring)."""
+    blocks = schema.get("device_blocks", set())
+    missing = {b for b in blocks if data.get(b) is None}
+    if not missing:
+        return missing
+    backends = {data[b].get("backend") for b in blocks - missing
+                if isinstance(data[b], dict)}
+    if not backends:
+        errors.append(f"{name}: every device block {sorted(blocks)} is "
+                      "missing — no rows were recorded")
+    elif backends <= {"cpu"}:
+        for b in sorted(missing & schema["required"]):
+            errors.append(f"{name}: device block {b!r} is null on a cpu "
+                          "record — only a chip run may skip it")
+    return missing
+
+
 def validate_file(path: Path, errors: list) -> dict:
     name = path.name
     schema = SCHEMAS.get(name)
@@ -162,8 +194,11 @@ def validate_file(path: Path, errors: list) -> dict:
     if unknown:
         errors.append(f"{name}: unknown keys {sorted(unknown)} — update "
                       "the schema here and the docs/reference.md table")
+    skipped = _missing_device_blocks(data, schema, name, errors)
     for flag in schema["parity"]:
-        _walk_flag(data, _flag_parts(flag), flag, errors, name)
+        parts = _flag_parts(flag)
+        if parts[0] not in skipped:
+            _walk_flag(data, parts, flag, errors, name)
     return data
 
 

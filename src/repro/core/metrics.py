@@ -165,11 +165,11 @@ def _gain_compact(hga: HypergraphArrays, phi: jnp.ndarray, k: int,
 
 def _resolve_gain_path(hga: HypergraphArrays, k: int, assemble: str) -> str:
     """Static (trace-time) path choice: "auto" consults the ops
-    dispatcher by (m_pad, k, backend); a concrete path name forces it
+    dispatcher by (k, backend); a concrete path name forces it
     (the FM move loop pins "segsum" — see ``refine._fm_pass_impl``)."""
     from repro.kernels import ops
     if assemble == "auto":
-        return ops.gain_path(hga.m_pad, k, incidence=hga.incident is not None)
+        return ops.gain_path(k, incidence=hga.incident is not None)
     return assemble
 
 
@@ -185,9 +185,9 @@ def gain_matrix(hga: HypergraphArrays, part: jnp.ndarray, k: int,
     gain[v, part[v]] == 0 by construction.
 
     Assembly is routed through the ``kernels.ops`` gain dispatcher (see
-    its docstring for the decision table): Pallas whole-table/streaming
-    kernels on compiled backends, segment-sum or the compact sparse path
-    on CPU.  All paths agree to float tolerance; within one path the
+    its docstring for the decision table): the streaming Pallas kernel
+    on compiled backends, segment-sum or the compact sparse path on
+    CPU.  All paths agree to float tolerance; within one path the
     scalar and vmapped population entry points agree bit-for-bit.
     """
     if phi is None:

@@ -1,11 +1,8 @@
-"""Version-portable wrappers for the handful of jax APIs that moved
-between 0.4.x and 0.6+.
+"""The mesh and shard_map spellings the repo uses, in one place.
 
-The repo targets the container's pinned jax (currently 0.4.37) but keeps
-working on newer releases where ``jax.shard_map``, ``jax.set_mesh`` and
-``jax.sharding.AxisType`` are the public spellings.  Everything that
-builds a mesh, enters a mesh context, or wraps a function in shard_map
-must go through this module.
+Everything that builds a mesh, enters a mesh context, or wraps a
+function in shard_map goes through this module, so a later JAX API move
+touches one file.
 """
 from __future__ import annotations
 
@@ -17,47 +14,27 @@ import numpy as np
 
 def make_mesh(shape: Sequence[int], axis_names: Sequence[str],
               devices: Optional[Sequence] = None):
-    """``jax.make_mesh`` with auto axis types where the arg exists.
+    """A mesh with auto axis types.
 
     ``devices`` restricts the mesh to an explicit device list (the
     elasticity path: a rebuilt mesh over the survivors of a device loss,
     ``popshard.local_devices``); the default uses every local device.
     """
+    axis_types = (jax.sharding.AxisType.Auto,) * len(axis_names)
     if devices is not None:
         arr = np.array(list(devices), dtype=object).reshape(tuple(shape))
-        axis_type = getattr(jax.sharding, "AxisType", None)
-        if axis_type is not None:
-            try:
-                return jax.sharding.Mesh(
-                    arr, tuple(axis_names),
-                    axis_types=(axis_type.Auto,) * len(axis_names))
-            except TypeError:
-                pass
-        return jax.sharding.Mesh(arr, tuple(axis_names))
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(tuple(shape), tuple(axis_names),
-                             axis_types=(axis_type.Auto,) * len(axis_names))
-    return jax.make_mesh(tuple(shape), tuple(axis_names))
+        return jax.sharding.Mesh(arr, tuple(axis_names),
+                                 axis_types=axis_types)
+    return jax.make_mesh(tuple(shape), tuple(axis_names),
+                         axis_types=axis_types)
 
 
 def use_mesh(mesh):
-    """Context manager activating ``mesh`` (``jax.set_mesh`` on new jax,
-    the plain mesh context manager on 0.4.x)."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    # On 0.4.x the Mesh object is itself a context manager; shard_map'd
-    # functions carry their mesh explicitly, so this is purely scoping.
-    return mesh
+    """Context manager activating ``mesh``."""
+    return jax.set_mesh(mesh)
 
 
 def shard_map(f, mesh, in_specs, out_specs):
-    """shard_map without replication checking, old- and new-API."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    from jax.experimental.shard_map import shard_map as exp_shard_map
-    return exp_shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
+    """shard_map without replication checking."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

@@ -8,5 +8,5 @@ ref.py          — pure-jnp oracles (test ground truth)
 """
 from . import ops, ref
 from .connectivity import connectivity_pallas, cutsize_pallas
-from .gain import gain_gather_pallas, gain_gather_batch_pallas
+from .gain import gain_stream_pallas, gain_stream_batch_pallas
 from .embedding_bag import embedding_bag_pallas

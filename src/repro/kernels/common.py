@@ -14,27 +14,27 @@ import jax.numpy as jnp
 #: the output tile and the scratch accumulator all fit at once.
 VMEM_BUDGET_BYTES = 16 * 2**20
 
-#: Hard cap on ``k`` for the single-word kernels.  Two independent
-#: derivations land on the same number:
-#:   * the connectivity/cutsize kernels pack "edge touches block j" into
-#:     one uint32 lane bitmask, so k is capped by the 32-bit VPU word;
-#:   * the whole-table gain kernel keeps the full [M, k] fp32 edge table
-#:     resident in VMEM — at the coarse-level ceiling M = 16K pinning
-#:     k at 32 bounds the table to 16K * 32 * 4 B = 2 MiB, an eighth of
-#:     ``VMEM_BUDGET_BYTES``, leaving room for the [block_n, D, k]
-#:     gather tile and double buffering.
-#: Beyond this, connectivity falls back to the XLA segment-sum and the
-#: gain dispatcher switches to the streaming kernel (edge-table tiling).
+#: Hard cap on ``k`` for the single-word kernels: the
+#: connectivity/cutsize kernels pack "edge touches block j" into one
+#: uint32 lane bitmask, so k is capped by the 32-bit VPU word.  Beyond
+#: it, connectivity falls back to the XLA segment-sum; the XLA gain
+#: assembly switches from the [P, k] segment-sum to the compact path.
 KERNEL_MAX_K = 32
 
-#: Budget for a whole [M, k] edge table resident in VMEM (the
-#: ``gain_gather_*`` kernels) — 1/8 of VMEM, see ``KERNEL_MAX_K``.
-GAIN_TABLE_VMEM_BYTES = VMEM_BUDGET_BYTES // 8
-
-#: Budget for one streamed tile of the ``gain_stream_*`` kernels: the
-#: [block_n, D, k] gather intermediate (the largest tensor the kernel
-#: materialises).  Block sizes are derived from it at trace time.
+#: Budget for one streamed [k, block_m] table tile of the
+#: ``gain_stream_*`` kernel.  Block sizes are derived from it at trace
+#: time.
 GAIN_STREAM_TILE_BYTES = VMEM_BUDGET_BYTES // 8
+
+#: Vertex-tile lanes of the gain kernel (a multiple of the 128-lane
+#: vreg width).
+GAIN_BLOCK_N = 256
+
+#: Upper bound on the edge rows per incidence-count tile of the gain
+#: kernel: the [GAIN_WINDOW_M, GAIN_BLOCK_N] f32 count matrix (512 KiB)
+#: is the largest tensor it materialises; the gather runs as a matmul of
+#: the table tile against it on the MXU.
+GAIN_WINDOW_M = 512
 
 
 #: Budget for one tile pair of the rating scatter kernel
@@ -45,7 +45,7 @@ RATING_TILE_BYTES = VMEM_BUDGET_BYTES // 8
 
 #: Routing bound for the rating kernel.  Its grid is dense over
 #: (segment tiles x candidate tiles) — quadratic in the candidate count,
-#: like the whole-table gain kernel it is the coarse/mid-level tool.
+#: so it is the coarse/mid-level tool.
 #: Above this candidate count the dispatcher falls back to the XLA
 #: segment-sum (sorted-scatter, linear).  32K candidates with the
 #: default 512x1024 tiles is ~2K grid steps.
@@ -74,16 +74,12 @@ def _pow2_floor(x: int, lo: int, hi: int) -> int:
     return int(min(max(p, lo), hi))
 
 
-def stream_block_n(d: int, k: int) -> int:
-    """Vertex-tile rows for the streaming gain kernels: the [bn, D, k]
-    gather tile must fit ``GAIN_STREAM_TILE_BYTES``."""
-    return _pow2_floor(GAIN_STREAM_TILE_BYTES // max(d * k * 4, 1), 8, 256)
-
-
 def stream_block_m(k: int) -> int:
     """Edge-table tile rows for the streaming gain kernels: the
-    [bm, k] table tile must fit ``GAIN_STREAM_TILE_BYTES``."""
-    return _pow2_floor(GAIN_STREAM_TILE_BYTES // max(k * 4, 1), 8, 512)
+    [k, bm] table tile must fit ``GAIN_STREAM_TILE_BYTES``, and bm is a
+    lane multiple no larger than the count window."""
+    return _pow2_floor(GAIN_STREAM_TILE_BYTES // max(k * 4, 1), 128,
+                       GAIN_WINDOW_M)
 
 
 def rating_blocks() -> tuple:

@@ -5,34 +5,38 @@ k-way gains.  Two stages:
 
   1. edge terms (cheap, done in jnp inside core/metrics.py): from
      Phi[M, k] compute ``becomes_internal[M, k]`` and ``was_internal[M]``.
-  2. **these kernels**: for each vertex, gather + sum the rows of its
+  2. **this kernel**: for each vertex, gather + sum the rows of its
      incident edges — a fused gather-reduce over the dual CSR, re-blocked
      as a padded incidence matrix ``incident[N, D]`` (pad = -1).
 
-Two kernel families, chosen by the dispatcher in ``kernels/ops.py``:
+The gather runs on the MXU as a one-hot matmul.  For a vertex tile of
+``bn`` vertices and an edge window of ``bm`` table rows the kernel
+builds the incidence-count matrix ``counts[bm, bn]`` (how often edge e
+appears among vertex v's D incident slots; pad slots match no row) one
+incident slot at a time, then
 
-* **Whole-table** (``gain_gather_pallas``): the per-edge table (M x k
-  fp32) sits whole in VMEM — sized for the coarse levels where FM runs
-  (m <= ~16k, k <= 32 -> 2 MB, see ``common.KERNEL_MAX_K``).  The gather
-  is a VMEM dynamic row gather (``jnp.take``), the reduction runs on the
-  VPU with a [bn, D, k] tile chosen to fit the VMEM budget.
+    gains^T[k, bn]  +=  bi^T[k, bm] @ counts  -  wi[1, bm] @ counts
 
-* **Streaming** (``gain_stream_pallas``): fine levels / large k, where
-  [M, k] exceeds VMEM.  The grid adds an edge-table axis: tile ``t``
-  sees only rows ``[t*block_m, (t+1)*block_m)`` of the per-edge tables,
-  gathers the incident edges that fall inside that window (everything
-  else masks to zero) and accumulates the partial gains into the output
-  tile, which stays resident in VMEM across all edge-table tiles of a
-  vertex tile (the TPU grid is sequential, so revisiting the same output
-  block is the idiomatic scratch accumulator).  No [M, k] table and no
-  [P, k] per-pin tensor is ever materialised whole.
+Every operand is 2-D with the vertex axis on the lanes, which is why
+the tables and the result travel transposed (``[k, M]`` / ``[k, N]``)
+and the incidence as ``[D, N]``: the TPU compiler tiles in (8, 128)
+blocks and has no 3-D row gather.
 
-The population-batched variants (``gain_gather_batch_pallas`` /
-``gain_stream_batch_pallas``) prepend an ``alpha`` grid axis: the
-incidence tile is SHARED across the alpha axis (same hypergraph for
-every member) while each member brings its own ``becomes_internal`` /
-``was_internal`` tables — the memetic population refines in one kernel
-launch.
+The kernel (``gain_stream_pallas``) streams the per-edge tables over a
+second grid axis: tile ``t`` sees only rows ``[t*block_m,
+(t+1)*block_m)`` of the tables and accumulates its partial gains into
+the output tile, which stays resident in VMEM across all edge-table
+tiles of a vertex tile (the TPU grid is sequential, so revisiting the
+same output block is the idiomatic scratch accumulator).  No [M, k]
+table and no [P, k] per-pin tensor is ever materialised whole, so any
+(M, k) fits.
+
+It launches population-batched: a leading ``alpha`` grid axis, squeezed
+out of the blocks, shares the incidence tile across members (same
+hypergraph) while each member brings its own ``becomes_internal`` /
+``was_internal`` tables.  The single-member entry point is the same
+launch with ``alpha = 1``, so a member's slice is bit-equal to its own
+single-member launch by construction.
 """
 from __future__ import annotations
 
@@ -42,114 +46,27 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .common import (pad_rows as _pad_rows, stream_block_m as _stream_bm,
-                     stream_block_n as _stream_bn)
+from .common import (GAIN_BLOCK_N, pad_rows as _pad_rows,
+                     stream_block_m as _stream_bm)
 
 
-def _gain_kernel(inc_ref, bi_ref, wi_ref, out_ref):
-    inc = inc_ref[...]                            # [bn, D] int32
-    bi = bi_ref[...]                              # [M, k] f32
-    wi = wi_ref[...]                              # [M] f32
-    valid = inc >= 0
-    safe = jnp.where(valid, inc, 0)
-    rows = jnp.take(bi, safe, axis=0)             # [bn, D, k]
-    rows = rows * valid[..., None]
-    loss = jnp.take(wi, safe, axis=0) * valid     # [bn, D]
-    out_ref[...] = rows.sum(axis=1) - loss.sum(axis=1, keepdims=True)
+def _gain_stream_kernel(inct_ref, bit_ref, wi_ref, out_ref):
+    """Partial gains^T [k, bn] of edge-table tile ``t``: bit [k, bm],
+    wi [1, bm], accumulated over the tiles into the output block."""
+    t = pl.program_id(2)                          # edge-table tile index
+    depth, bn = inct_ref.shape
+    block_m = bit_ref.shape[1]
+    rows = (jax.lax.broadcasted_iota(jnp.int32, (block_m, bn), 0)
+            + t * block_m)
 
+    def slot(d, counts):
+        return counts + (inct_ref[pl.ds(d, 1), :] == rows).astype(jnp.float32)
 
-@functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
-def gain_gather_pallas(incident: jnp.ndarray, becomes_internal: jnp.ndarray,
-                       was_internal: jnp.ndarray, block_n: int = 256,
-                       interpret: bool = True) -> jnp.ndarray:
-    """gains[N, k] = sum_d bi[incident[v, d]] - sum_d wi[incident[v, d]].
-
-    ``incident`` rows need NOT be a multiple of ``block_n``: the kernel
-    pads internally (pad rows gather nothing) and slices the result.
-    """
-    n, _ = incident.shape
-    m, k = becomes_internal.shape
-    incident = _pad_rows(incident, block_n, -1)
-    n_pad, d = incident.shape
-    grid = (n_pad // block_n,)
-    out = pl.pallas_call(
-        _gain_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_n, d), lambda i: (i, 0)),   # incidence tile
-            pl.BlockSpec((m, k), lambda i: (0, 0)),         # whole bi table
-            pl.BlockSpec((m,), lambda i: (0,)),             # whole wi table
-        ],
-        out_specs=pl.BlockSpec((block_n, k), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_pad, k), jnp.float32),
-        interpret=interpret,
-    )(incident, becomes_internal, was_internal)
-    return out[:n]
-
-
-def _gain_batch_kernel(inc_ref, bi_ref, wi_ref, out_ref):
-    inc = inc_ref[...]                            # [bn, D] int32 (shared)
-    bi = bi_ref[...]                              # [1, M, k] member tables
-    wi = wi_ref[...]                              # [1, M]
-    valid = inc >= 0
-    safe = jnp.where(valid, inc, 0)
-    rows = jnp.take(bi[0], safe, axis=0)          # [bn, D, k]
-    rows = rows * valid[..., None]
-    loss = jnp.take(wi[0], safe, axis=0) * valid  # [bn, D]
-    out_ref[...] = (rows.sum(axis=1)
-                    - loss.sum(axis=1, keepdims=True))[None]
-
-
-@functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
-def gain_gather_batch_pallas(incident: jnp.ndarray,
-                             becomes_internal: jnp.ndarray,
-                             was_internal: jnp.ndarray, block_n: int = 256,
-                             interpret: bool = True) -> jnp.ndarray:
-    """Population-batched gain assembly.
-
-    incident: [N, D] int32 (shared by all members, pad = -1)
-    becomes_internal: [alpha, M, k] ; was_internal: [alpha, M]
-    returns gains [alpha, N, k].
-
-    Grid ``(alpha, N // block_n)``: the incidence tile index map ignores
-    the population index, so the same vertex tile serves every member
-    while per-member edge tables stream through the second operand.
-    """
-    n, _ = incident.shape
-    alpha, m, k = becomes_internal.shape
-    assert was_internal.shape == (alpha, m)
-    incident = _pad_rows(incident, block_n, -1)
-    n_pad, d = incident.shape
-    grid = (alpha, n_pad // block_n)
-    out = pl.pallas_call(
-        _gain_batch_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_n, d), lambda a, i: (i, 0)),  # shared tile
-            pl.BlockSpec((1, m, k), lambda a, i: (a, 0, 0)),  # member bi
-            pl.BlockSpec((1, m), lambda a, i: (a, 0)),        # member wi
-        ],
-        out_specs=pl.BlockSpec((1, block_n, k), lambda a, i: (a, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((alpha, n_pad, k), jnp.float32),
-        interpret=interpret,
-    )(incident, becomes_internal, was_internal)
-    return out[:, :n]
-
-
-# --------------------------------------------------------------------------
-# streaming fine-level kernels: tile the edge tables, accumulate in VMEM
-# --------------------------------------------------------------------------
-def _gain_stream_kernel(inc_ref, bi_ref, wi_ref, out_ref, *, block_m: int):
-    t = pl.program_id(1)                          # edge-table tile index
-    inc = inc_ref[...]                            # [bn, D] int32
-    bi = bi_ref[...]                              # [bm, k] table tile
-    wi = wi_ref[...]                              # [bm]
-    local = inc - t * block_m                     # edge id within the tile
-    valid = (inc >= 0) & (local >= 0) & (local < block_m)
-    safe = jnp.where(valid, local, 0)
-    rows = jnp.take(bi, safe, axis=0) * valid[..., None]   # [bn, D, k]
-    loss = jnp.take(wi, safe, axis=0) * valid              # [bn, D]
-    partial = rows.sum(axis=1) - loss.sum(axis=1, keepdims=True)
+    counts = jax.lax.fori_loop(0, depth, slot,
+                               jnp.zeros(rows.shape, jnp.float32))
+    dot = functools.partial(jnp.dot, precision=jax.lax.Precision.HIGHEST,
+                            preferred_element_type=jnp.float32)
+    partial = dot(bit_ref[...], counts) - dot(wi_ref[...], counts)
 
     # the output tile doubles as the VMEM scratch accumulator: its index
     # map ignores t, so the same block stays resident across the whole
@@ -161,65 +78,15 @@ def _gain_stream_kernel(inc_ref, bi_ref, wi_ref, out_ref, *, block_m: int):
     out_ref[...] += partial
 
 
-@functools.partial(jax.jit, static_argnames=("block_n", "block_m",
-                                             "interpret"))
-def gain_stream_pallas(incident: jnp.ndarray, becomes_internal: jnp.ndarray,
-                       was_internal: jnp.ndarray, block_n: int | None = None,
-                       block_m: int | None = None, interpret: bool = True
-                       ) -> jnp.ndarray:
-    """Streaming gain assembly for fine levels / large k.
-
-    Same contract as ``gain_gather_pallas`` but the per-edge tables are
-    tiled over a second grid axis instead of sitting whole in VMEM, so
-    any (M, k) fits.  Block sizes default to the largest power of two
-    that keeps the [bn, D, k] gather tile and the [bm, k] table tile
-    within ``common.GAIN_STREAM_TILE_BYTES``.
-    """
-    n, d = incident.shape
-    m, k = becomes_internal.shape
-    if block_n is None:
-        block_n = _stream_bn(d, k)
-    if block_m is None:
-        block_m = _stream_bm(k)
-    incident = _pad_rows(incident, block_n, -1)
-    becomes_internal = _pad_rows(becomes_internal, block_m, 0.0)
-    was_internal = _pad_rows(was_internal, block_m, 0.0)
-    n_pad = incident.shape[0]
-    m_pad = becomes_internal.shape[0]
-    grid = (n_pad // block_n, m_pad // block_m)   # edge axis innermost
-    out = pl.pallas_call(
-        functools.partial(_gain_stream_kernel, block_m=block_m),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_n, d), lambda i, t: (i, 0)),   # vertex tile
-            pl.BlockSpec((block_m, k), lambda i, t: (t, 0)),   # table tile
-            pl.BlockSpec((block_m,), lambda i, t: (t,)),
-        ],
-        out_specs=pl.BlockSpec((block_n, k), lambda i, t: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_pad, k), jnp.float32),
-        interpret=interpret,
-    )(incident, becomes_internal, was_internal)
-    return out[:n]
-
-
-def _gain_stream_batch_kernel(inc_ref, bi_ref, wi_ref, out_ref, *,
-                              block_m: int):
-    t = pl.program_id(2)
-    inc = inc_ref[...]                            # [bn, D] (shared)
-    bi = bi_ref[...]                              # [1, bm, k] member tile
-    wi = wi_ref[...]                              # [1, bm]
-    local = inc - t * block_m
-    valid = (inc >= 0) & (local >= 0) & (local < block_m)
-    safe = jnp.where(valid, local, 0)
-    rows = jnp.take(bi[0], safe, axis=0) * valid[..., None]
-    loss = jnp.take(wi[0], safe, axis=0) * valid
-    partial = (rows.sum(axis=1) - loss.sum(axis=1, keepdims=True))[None]
-
-    @pl.when(t == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    out_ref[...] += partial
+def _transposed_operands(incident, becomes_internal, was_internal,
+                         block_n, block_m):
+    """Pad and transpose to the kernels' lane-major layouts:
+    incident^T [D, N_pad], bi^T [alpha, k, M_pad], wi [alpha, 1, M_pad]."""
+    inct = _pad_rows(incident, block_n, -1).T
+    m_tail = (-becomes_internal.shape[1]) % block_m
+    bit = jnp.pad(becomes_internal, ((0, 0), (0, m_tail), (0, 0)))
+    wi = jnp.pad(was_internal, ((0, 0), (0, m_tail)))
+    return inct, jnp.swapaxes(bit, 1, 2), wi[:, None, :]
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "block_m",
@@ -227,7 +94,7 @@ def _gain_stream_batch_kernel(inc_ref, bi_ref, wi_ref, out_ref, *,
 def gain_stream_batch_pallas(incident: jnp.ndarray,
                              becomes_internal: jnp.ndarray,
                              was_internal: jnp.ndarray,
-                             block_n: int | None = None,
+                             block_n: int = GAIN_BLOCK_N,
                              block_m: int | None = None,
                              interpret: bool = True) -> jnp.ndarray:
     """Population-batched streaming gain assembly.
@@ -237,33 +104,42 @@ def gain_stream_batch_pallas(incident: jnp.ndarray,
     returns gains [alpha, N, k].  Grid ``(alpha, N//bn, M//bm)`` — the
     shared incidence tile ignores the population index, each member
     streams its own edge-table tiles, and the per-(member, vertex-tile)
-    output block accumulates across the edge sweep exactly like the
-    single-member kernel (bit-identical per-member results).
+    output block accumulates across the edge sweep.  Block sizes default
+    to the largest that keep the [bm, bn] count tile and the [k, bm]
+    table tile within ``common.GAIN_STREAM_TILE_BYTES``.
     """
     n, d = incident.shape
     alpha, m, k = becomes_internal.shape
     assert was_internal.shape == (alpha, m)
-    if block_n is None:
-        block_n = _stream_bn(d, k)
     if block_m is None:
         block_m = _stream_bm(k)
-    incident = _pad_rows(incident, block_n, -1)
-    m_tail = (-m) % block_m
-    bi = jnp.pad(becomes_internal, ((0, 0), (0, m_tail), (0, 0)))
-    wi = jnp.pad(was_internal, ((0, 0), (0, m_tail)))
-    n_pad = incident.shape[0]
-    m_pad = bi.shape[1]
-    grid = (alpha, n_pad // block_n, m_pad // block_m)
+    inct, bit, wi = _transposed_operands(incident, becomes_internal,
+                                         was_internal, block_n, block_m)
+    n_pad, m_pad = inct.shape[1], bit.shape[2]
     out = pl.pallas_call(
-        functools.partial(_gain_stream_batch_kernel, block_m=block_m),
-        grid=grid,
+        _gain_stream_kernel,
+        grid=(alpha, n_pad // block_n, m_pad // block_m),  # edge axis last
         in_specs=[
-            pl.BlockSpec((block_n, d), lambda a, i, t: (i, 0)),
-            pl.BlockSpec((1, block_m, k), lambda a, i, t: (a, t, 0)),
-            pl.BlockSpec((1, block_m), lambda a, i, t: (a, t)),
+            pl.BlockSpec((d, block_n), lambda a, i, t: (0, i)),
+            pl.BlockSpec((None, k, block_m), lambda a, i, t: (a, 0, t)),
+            pl.BlockSpec((None, 1, block_m), lambda a, i, t: (a, 0, t)),
         ],
-        out_specs=pl.BlockSpec((1, block_n, k), lambda a, i, t: (a, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((alpha, n_pad, k), jnp.float32),
+        out_specs=pl.BlockSpec((None, k, block_n), lambda a, i, t: (a, 0, i)),
+        out_shape=jax.ShapeDtypeStruct((alpha, k, n_pad), jnp.float32),
         interpret=interpret,
-    )(incident, bi, wi)
-    return out[:, :n]
+    )(inct, bit, wi)
+    return jnp.swapaxes(out, 1, 2)[:, :n]
+
+
+def gain_stream_pallas(incident: jnp.ndarray, becomes_internal: jnp.ndarray,
+                       was_internal: jnp.ndarray, block_n: int = GAIN_BLOCK_N,
+                       block_m: int | None = None, interpret: bool = True
+                       ) -> jnp.ndarray:
+    """gains[N, k] = sum_d bi[incident[v, d]] - sum_d wi[incident[v, d]].
+
+    ``incident`` rows need NOT be a multiple of ``block_n``: the kernel
+    pads internally (pad rows gather nothing) and slices the result.
+    The ``alpha = 1`` batch launch."""
+    return gain_stream_batch_pallas(incident, becomes_internal[None],
+                                    was_internal[None], block_n=block_n,
+                                    block_m=block_m, interpret=interpret)[0]

@@ -10,38 +10,40 @@ that revisits the level.
 Interpreter mode is derived from the active backend: on CPU the Pallas
 interpreter executes the kernel bodies faithfully; on TPU/GPU the real
 kernels compile.  Override with ``REPRO_PALLAS_INTERPRET=0|1`` (anything
-else, or unset, means auto).
+else, or unset, means auto); ``1`` is refused on a non-CPU backend, where
+it would run every kernel on the host instead of the device.
 
 Gain-path dispatch
 ------------------
-``gain_path(m, k)`` picks how ``core.metrics.gain_matrix`` assembles the
-[n, k] gain matrix from the per-edge tables, keyed on ``(m, k, backend)``
-(all static at trace time):
+``gain_path(k)`` picks how ``core.metrics.gain_matrix`` assembles the
+[n, k] gain matrix from the per-edge tables, on every backend:
 
 ====================  =====================================================
 path                  chosen when
 ====================  =====================================================
-``"table"``           compiled backend, ``k <= KERNEL_MAX_K`` and the whole
-                      [M, k] table fits ``GAIN_TABLE_VMEM_BYTES`` (2 MiB)
-                      -> ``gain_gather_pallas`` (table resident in VMEM)
-``"stream"``          compiled backend, everything larger -> the streaming
-                      kernel tiles the edge tables over a second grid axis
-                      and accumulates partial gains in the resident output
-                      tile; nothing [M, k]- or [P, k]-sized materialises
-``"segsum"``          CPU / interpret backend, ``k <= KERNEL_MAX_K``: the
-                      XLA reference ([P, k] per-pin segment-sum)
-``"compact"``         CPU / interpret backend, ``k > KERNEL_MAX_K``: sparse
-                      XLA assembly exploiting that ``becomes_internal`` has
-                      at most two nonzeros per edge — O(P) scatter instead
-                      of O(P * k) (see ``core.metrics.gain_matrix``)
+``"segsum"``          ``k <= KERNEL_MAX_K``: the XLA reference ([P, k]
+                      per-pin segment-sum)
+``"compact"``         ``k > KERNEL_MAX_K``: sparse XLA assembly exploiting
+                      that ``becomes_internal`` has at most two nonzeros
+                      per edge — O(P) scatter instead of O(P * k) (see
+                      ``core.metrics.gain_matrix``)
+``"stream"``          only when forced: ``gain_stream_pallas``, the edge
+                      tables streamed over a second grid axis with partial
+                      gains accumulated in the resident output tile
 ====================  =====================================================
 
-``REPRO_GAIN_PATH=table|stream|segsum|compact`` forces a path (used by the
+The Pallas kernel compiles for the TPU but is off the automatic route
+because it is slower there: it sweeps every (vertex tile, edge tile)
+pair, O(n * m) work, where the XLA scatters are O(P * k).  On one TPU
+v5e at ibm01's published size (n = 12,752, m = 14,111, 38,311 pins),
+k = 64, alpha = 7, one population gain matrix took 91.6 ms on the
+kernel against 11.8 ms on ``segsum`` and 14.9 ms on ``compact``.
+
+``REPRO_GAIN_PATH=stream|segsum|compact`` forces a path (used by the
 parity tests and the CI benchmark smoke); ``auto``/unset means the table
-above.  The kernel paths need the dense incidence layout, which
-``HypergraphArrays.from_host`` attaches when ``gain_layout_enabled()``
-says a kernel path is reachable (so CPU test runs don't pay for layouts
-they never read).
+above.  The kernel path needs the dense incidence layout, which
+``HypergraphArrays.from_host`` attaches only when the kernel is forced
+(``gain_layout_enabled()``), so no run pays for a layout it never reads.
 """
 from __future__ import annotations
 
@@ -54,11 +56,10 @@ import jax.numpy as jnp
 from repro.core.hypergraph import Hypergraph
 from repro.env import warn_env_once
 from . import ref
-from .common import (GAIN_TABLE_VMEM_BYTES, GAIN_STREAM_TILE_BYTES,  # noqa: F401 (re-exported)
+from .common import (GAIN_STREAM_TILE_BYTES,  # noqa: F401 (re-exported)
                      KERNEL_MAX_K, RATING_KERNEL_MAX_C, VMEM_BUDGET_BYTES)
 from .connectivity import connectivity_pallas, cutsize_pallas
-from .gain import (gain_gather_pallas, gain_gather_batch_pallas,
-                   gain_stream_pallas, gain_stream_batch_pallas)
+from .gain import gain_stream_pallas, gain_stream_batch_pallas
 from .embedding_bag import embedding_bag_pallas
 from .rating import rating_scatter_pallas, rating_scatter_batch_pallas
 
@@ -74,6 +75,12 @@ def interpret_mode() -> bool:
     global _INTERPRET_CACHE
     env = os.environ.get("REPRO_PALLAS_INTERPRET", "auto").strip().lower()
     if env in ("1", "true", "yes"):
+        backend = jax.default_backend()
+        if backend != "cpu":
+            raise RuntimeError(
+                f"REPRO_PALLAS_INTERPRET={env} on the {backend} backend "
+                "would interpret every Pallas kernel on the host instead "
+                "of running it on the device; unset it (or set 0/auto)")
         return True
     if env in ("0", "false", "no"):
         return False
@@ -88,7 +95,7 @@ def interpret_mode() -> bool:
 # --------------------------------------------------------------------------
 # gain-path dispatch
 # --------------------------------------------------------------------------
-GAIN_PATHS = ("table", "stream", "segsum", "compact")
+GAIN_PATHS = ("stream", "segsum", "compact")
 
 
 def _gain_env() -> str:
@@ -101,40 +108,25 @@ def _gain_env() -> str:
 
 def gain_layout_enabled() -> bool:
     """Should ``HypergraphArrays.from_host`` attach the dense incidence
-    layout?  True iff a Pallas gain path is reachable (compiled backend,
-    or a kernel path forced via ``REPRO_GAIN_PATH``)."""
-    env = _gain_env()
-    if env in ("table", "stream"):
-        return True
-    if env in ("segsum", "compact"):
-        return False
-    return not interpret_mode()
+    layout?  True iff the Pallas gain path is forced via
+    ``REPRO_GAIN_PATH``."""
+    return _gain_env() == "stream"
 
 
-def gain_path(m: int, k: int, incidence: bool = True) -> str:
-    """Resolve the gain-assembly path for padded table size ``m`` and
-    ``k`` blocks (see module docstring for the decision table).
-    ``incidence``: whether the dense incidence layout is available —
-    without it the kernel paths are unreachable and the XLA paths are
-    used regardless of backend."""
+def gain_path(k: int, incidence: bool = True) -> str:
+    """Resolve the gain-assembly path for ``k`` blocks (see module
+    docstring for the decision table).  ``incidence``: whether the
+    dense incidence layout is available — without it the forced kernel
+    path is unreachable and the XLA path for ``k`` is used."""
     env = _gain_env()
-    if env in ("segsum", "compact"):
+    if env in ("segsum", "compact") or (env == "stream" and incidence):
         return env
-    if env in ("table", "stream") and incidence:
-        return env
-    if interpret_mode() or not incidence:
-        return "segsum" if k <= KERNEL_MAX_K else "compact"
-    if k <= KERNEL_MAX_K and m * k * 4 <= GAIN_TABLE_VMEM_BYTES:
-        return "table"
-    return "stream"
+    return "segsum" if k <= KERNEL_MAX_K else "compact"
 
 
 def gain_assemble(incident: jnp.ndarray, becomes_internal: jnp.ndarray,
                   was_internal: jnp.ndarray, path: str) -> jnp.ndarray:
-    """Kernel-path gain assembly (``path`` in {"table", "stream"})."""
-    if path == "table":
-        return gain_gather_pallas(incident, becomes_internal, was_internal,
-                                  interpret=interpret_mode())
+    """Kernel-path gain assembly (``path`` == "stream")."""
     if path == "stream":
         return gain_stream_pallas(incident, becomes_internal, was_internal,
                                   interpret=interpret_mode())
@@ -144,10 +136,6 @@ def gain_assemble(incident: jnp.ndarray, becomes_internal: jnp.ndarray,
 def gain_assemble_batch(incident: jnp.ndarray, becomes_internal: jnp.ndarray,
                         was_internal: jnp.ndarray, path: str) -> jnp.ndarray:
     """Population-batched kernel-path gain assembly."""
-    if path == "table":
-        return gain_gather_batch_pallas(incident, becomes_internal,
-                                        was_internal,
-                                        interpret=interpret_mode())
     if path == "stream":
         return gain_stream_batch_pallas(incident, becomes_internal,
                                         was_internal,
@@ -265,7 +253,7 @@ def gain_gather(incident: jnp.ndarray, becomes_internal: jnp.ndarray,
                 was_internal: jnp.ndarray, use_kernel: bool = True
                 ) -> jnp.ndarray:
     if use_kernel:
-        return gain_gather_pallas(incident, becomes_internal, was_internal,
+        return gain_stream_pallas(incident, becomes_internal, was_internal,
                                   interpret=interpret_mode())
     return ref.gain_gather_ref(incident, becomes_internal, was_internal)
 
@@ -280,7 +268,7 @@ def gain_gather_batch(incident: jnp.ndarray, becomes_internal: jnp.ndarray,
     [alpha, M] -> gains [alpha, N, k].
     """
     if use_kernel:
-        return gain_gather_batch_pallas(incident, becomes_internal,
+        return gain_stream_batch_pallas(incident, becomes_internal,
                                         was_internal,
                                         interpret=interpret_mode())
     return ref.gain_gather_batch_ref(incident, becomes_internal,

@@ -49,21 +49,23 @@ def gain_stream_ref(incident: jnp.ndarray, becomes_internal: jnp.ndarray,
     """Tile-order oracle for the streaming kernel: same result as
     ``gain_gather_ref`` but accumulated edge-tile by edge-tile, pinning
     down the accumulation semantics ``gain_stream_pallas`` must follow
-    (each tile contributes sum-over-D of its masked rows)."""
-    m = becomes_internal.shape[0]
-    out = jnp.zeros((incident.shape[0], becomes_internal.shape[1]),
-                    jnp.float32)
-    for lo in range(0, m, block_m):
-        bi = becomes_internal[lo:lo + block_m]
-        wi = was_internal[lo:lo + block_m]
-        local = incident - lo
-        valid = (incident >= 0) & (local >= 0) & (local < bi.shape[0])
-        safe = jnp.where(valid, local, 0)
-        rows = bi[safe] * valid[..., None]
-        loss = wi[safe] * valid
-        partial = rows.sum(axis=1) - loss.sum(axis=1, keepdims=True)
-        out = out + partial        # accumulate whole partials, as the
-    return out                     # kernel's out_ref += does
+    (each tile contributes its table window times the tile's
+    incidence-count matrix)."""
+    m, k = becomes_internal.shape
+    m_pad = -(-m // block_m) * block_m
+    bit = jnp.pad(becomes_internal, ((0, m_pad - m), (0, 0))).T   # [k, M]
+    wi = jnp.pad(was_internal, (0, m_pad - m))[None]              # [1, M]
+    out = jnp.zeros((k, incident.shape[0]), jnp.float32)
+    for lo in range(0, m_pad, block_m):
+        rows = jnp.arange(lo, lo + block_m, dtype=jnp.int32)[:, None]
+        counts = jnp.zeros((block_m, incident.shape[0]), jnp.float32)
+        for d in range(incident.shape[1]):
+            counts = counts + (incident[:, d][None, :] == rows)
+        w_bi, w_wi = bit[:, lo:lo + block_m], wi[:, lo:lo + block_m]
+        hi = jax.lax.Precision.HIGHEST
+        out = out + (jnp.dot(w_bi, counts, precision=hi)
+                     - jnp.dot(w_wi, counts, precision=hi))
+    return out.T
 
 
 def gain_gather_batch_ref(incident: jnp.ndarray,

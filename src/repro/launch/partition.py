@@ -16,6 +16,7 @@ from repro.core import (ImpartConfig, impart_partition,
                         refine)
 from repro.data.hypergraphs import (titan_like, ispd_like, BENCH_TITAN,
                                     BENCH_ISPD)
+from repro.env import enable_compile_cache
 
 
 def main():
@@ -31,6 +32,7 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--out", default="")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.design in BENCH_TITAN:
         hg = titan_like(args.design, scale=args.scale)

@@ -103,3 +103,56 @@ def test_unregistered_artifact_fails(tmp_path):
     proc = _run("--baseline", str(base), "--candidate", str(cand))
     assert proc.returncode == 1
     assert "no schema registered" in proc.stderr
+
+
+@pytest.mark.parametrize("name,block,backend,ok", [
+    ("BENCH_service.json", "multi_device", "tpu", True),
+    ("BENCH_service.json", "single_device", "tpu", True),
+    ("BENCH_service.json", "multi_device", "cpu", False),
+    ("BENCH_incremental.json", "multi_device", "tpu", True),
+    ("BENCH_incremental.json", "multi_device", "cpu", False),
+    ("BENCH_modelshard.json", "forced", "tpu", True),
+    ("BENCH_modelshard.json", "forced", "cpu", False),
+])
+def test_chip_record_may_skip_forced_block(tmp_path, name, block, backend,
+                                           ok):
+    """A chip run records no forced-host-device rows: the gate accepts
+    a null block only when another block names a non-CPU backend, and
+    still checks the parity flags of the blocks that are there."""
+    base, cand = tmp_path / "base", tmp_path / "cand"
+    base.mkdir(), cand.mkdir()
+    data = json.loads((ROOT / name).read_text())
+    if name == "BENCH_modelshard.json":
+        data["local"] = dict(data["forced"], devices=4)
+    kept = next(b for b in ("single_device", "multi_device", "local")
+                if b in data and b != block)
+    data[kept]["backend"] = backend
+    data[block] = None
+    (cand / name).write_text(json.dumps(data))
+    proc = _run("--baseline", str(base), "--candidate", str(cand))
+    assert (proc.returncode == 0) == ok, proc.stderr
+    if not ok:
+        assert "only a chip run may skip it" in proc.stderr
+
+    if ok:   # the remaining block's parity flags are still enforced
+        if name == "BENCH_modelshard.json":
+            data[kept]["parity_gate"]["bit_equal"] = False
+        else:
+            data[kept]["rows"][0][
+                "cuts_equal" if name == "BENCH_service.json"
+                else "migration_within_budget"] = False
+        (cand / name).write_text(json.dumps(data))
+        proc = _run("--baseline", str(base), "--candidate", str(cand))
+        assert proc.returncode == 1
+        assert "parity flag" in proc.stderr
+
+
+def test_record_with_no_device_rows_fails(tmp_path):
+    base, cand = tmp_path / "base", tmp_path / "cand"
+    base.mkdir(), cand.mkdir()
+    data = json.loads((ROOT / "BENCH_service.json").read_text())
+    data["single_device"] = data["multi_device"] = None
+    (cand / "BENCH_service.json").write_text(json.dumps(data))
+    proc = _run("--baseline", str(base), "--candidate", str(cand))
+    assert proc.returncode == 1
+    assert "no rows were recorded" in proc.stderr

@@ -3,9 +3,9 @@
 Three layers under test:
 
 * kernel parity — ``gain_stream_pallas`` (edge-table tiling + VMEM
-  accumulation) against the whole-table kernel and the jnp oracles,
-  across odd shapes, degree-0 vertices, unit edges and large k;
-* the dispatcher — ``ops.gain_path`` routing by (m, k, backend) and the
+  accumulation) against the jnp oracles, across odd shapes, degree-0
+  vertices, unit edges and large k;
+* the dispatcher — ``ops.gain_path`` routing by k on every backend and the
   ``REPRO_GAIN_PATH`` override, plus all paths agreeing through
   ``metrics.gain_matrix``;
 * the engine — the fused on-device LP attempt loop reproducing the
@@ -22,8 +22,7 @@ import pytest
 from repro.core import metrics, refine
 from repro.core.hypergraph import Hypergraph
 from repro.kernels import ops, ref
-from repro.kernels.gain import (gain_gather_pallas, gain_stream_pallas,
-                                gain_stream_batch_pallas)
+from repro.kernels.gain import gain_stream_pallas, gain_stream_batch_pallas
 
 
 # --------------------------------------------------------------------------
@@ -32,7 +31,7 @@ from repro.kernels.gain import (gain_gather_pallas, gain_stream_pallas,
 @pytest.mark.parametrize("n,d,m,k", [
     (256, 8, 128, 4),      # block-aligned
     (300, 8, 130, 5),      # n and m both off-block
-    (256, 16, 1024, 40),   # k > KERNEL_MAX_K: whole-table would blow VMEM
+    (256, 16, 1024, 40),   # k > KERNEL_MAX_K
     (100, 4, 50, 70),      # tiny m, large k
     (64, 8, 513, 3),       # m one past a block boundary
 ])
@@ -47,11 +46,6 @@ def test_gain_stream_parity(n, d, m, k):
     want = ref.gain_gather_ref(jnp.asarray(incident), jnp.asarray(bi),
                                jnp.asarray(wi))
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-4, atol=1e-4)
-    # and against the whole-table kernel (same inputs, different tiling)
-    table = gain_gather_pallas(jnp.asarray(incident), jnp.asarray(bi),
-                               jnp.asarray(wi))
-    np.testing.assert_allclose(np.asarray(got), np.asarray(table),
                                rtol=1e-4, atol=1e-4)
 
 
@@ -99,29 +93,27 @@ def test_gain_path_routing(monkeypatch):
     monkeypatch.delenv("REPRO_GAIN_PATH", raising=False)
     # CPU container -> interpret mode -> XLA paths by k
     monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
-    assert ops.gain_path(1024, 8) == "segsum"
-    assert ops.gain_path(1024, ops.KERNEL_MAX_K) == "segsum"
-    assert ops.gain_path(1024, ops.KERNEL_MAX_K + 1) == "compact"
+    assert ops.gain_path(8) == "segsum"
+    assert ops.gain_path(ops.KERNEL_MAX_K) == "segsum"
+    assert ops.gain_path(ops.KERNEL_MAX_K + 1) == "compact"
     assert not ops.gain_layout_enabled()
-    # compiled backend -> kernels, whole-table only while it fits VMEM
+    # compiled backend -> the same XLA paths: the kernel is slower there
+    # and only runs when forced
     monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
-    assert ops.gain_path(1024, 8) == "table"
-    small_m = ops.GAIN_TABLE_VMEM_BYTES // (32 * 4)
-    assert ops.gain_path(small_m, 32) == "table"
-    assert ops.gain_path(small_m + 1, 32) == "stream"
-    assert ops.gain_path(1024, 64) == "stream"
-    # no incidence layout -> kernels unreachable
-    assert ops.gain_path(1024, 8, incidence=False) == "segsum"
-    assert ops.gain_path(1024, 64, incidence=False) == "compact"
-    assert ops.gain_layout_enabled()
+    assert ops.gain_path(2) == "segsum"
+    assert ops.gain_path(ops.KERNEL_MAX_K) == "segsum"
+    assert ops.gain_path(64) == "compact"
+    assert not ops.gain_layout_enabled()
     # explicit override wins
     monkeypatch.setenv("REPRO_GAIN_PATH", "compact")
-    assert ops.gain_path(16, 2) == "compact"
+    assert ops.gain_path(2) == "compact"
     assert not ops.gain_layout_enabled()
     monkeypatch.setenv("REPRO_GAIN_PATH", "stream")
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
-    assert ops.gain_path(1 << 20, 1024) == "stream"
+    assert ops.gain_path(1024) == "stream"
     assert ops.gain_layout_enabled()
+    # forced kernel without an incidence layout -> the XLA path for k
+    assert ops.gain_path(8, incidence=False) == "segsum"
+    assert ops.gain_path(64, incidence=False) == "compact"
 
 
 def _random_hg(rng, n=60, m=110, unit_edges=True):
@@ -151,29 +143,28 @@ def test_compact_assembly_matches_segsum(k):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-@pytest.mark.parametrize("path", ["table", "stream"])
-def test_gain_matrix_kernel_paths_end_to_end(path, monkeypatch):
+@pytest.mark.parametrize("k", [8, 40])
+def test_gain_matrix_kernel_paths_end_to_end(k, monkeypatch):
     """gain_matrix / gain_matrix_population routed through the Pallas
-    kernels (forced via env) match the segsum reference on a real
-    hypergraph, scalar and population."""
-    monkeypatch.setenv("REPRO_GAIN_PATH", path)
+    kernel (forced via env) match the segsum reference on a real
+    hypergraph, scalar and population, below and above KERNEL_MAX_K."""
+    monkeypatch.setenv("REPRO_GAIN_PATH", "stream")
     jax.clear_caches()
     try:
         rng = np.random.default_rng(11)
         hg = _random_hg(rng)
         hga = hg.arrays()
         assert hga.incident is not None       # layout attached when forced
-        for k in (8, 40):
-            parts = jnp.stack([
-                refine.pad_part(rng.integers(0, k, hg.n).astype(np.int32),
-                                hga.n_pad) for _ in range(3)])
-            want = np.asarray(metrics.gain_matrix_jit(
-                hga, parts[0], k, assemble="segsum"))
-            got = np.asarray(metrics.gain_matrix_jit(hga, parts[0], k))
-            np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
-            gotp = np.asarray(metrics.gain_matrix_population(hga, parts, k))
-            # population slices bit-equal the scalar kernel path
-            np.testing.assert_array_equal(gotp[0], got)
+        parts = jnp.stack([
+            refine.pad_part(rng.integers(0, k, hg.n).astype(np.int32),
+                            hga.n_pad) for _ in range(3)])
+        want = np.asarray(metrics.gain_matrix_jit(
+            hga, parts[0], k, assemble="segsum"))
+        got = np.asarray(metrics.gain_matrix_jit(hga, parts[0], k))
+        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
+        gotp = np.asarray(metrics.gain_matrix_population(hga, parts, k))
+        # population slices bit-equal the scalar kernel path
+        np.testing.assert_array_equal(gotp[0], got)
     finally:
         jax.clear_caches()                    # drop env-baked traces
 
@@ -265,10 +256,11 @@ def test_fm_device_placement_cache(tiny_hg):
 
 def test_kernel_gate_constant():
     """The k-gate for the bitmask kernels is the shared named constant
-    (was a magic 32 in two call sites)."""
-    from repro.kernels.common import KERNEL_MAX_K, GAIN_TABLE_VMEM_BYTES, \
-        VMEM_BUDGET_BYTES
-    assert ops.KERNEL_MAX_K == KERNEL_MAX_K == 32
-    assert GAIN_TABLE_VMEM_BYTES * 8 == VMEM_BUDGET_BYTES
-    # the derivation in the comment: 16K x 32 fp32 table fits the budget
-    assert 16 * 1024 * KERNEL_MAX_K * 4 <= GAIN_TABLE_VMEM_BYTES
+    (was a magic 32 in two call sites): one uint32 lane bitmask."""
+    from repro.kernels.common import (KERNEL_MAX_K, GAIN_STREAM_TILE_BYTES,
+                                      VMEM_BUDGET_BYTES, stream_block_m)
+    assert ops.KERNEL_MAX_K == KERNEL_MAX_K == np.iinfo(np.uint32).bits
+    assert GAIN_STREAM_TILE_BYTES * 8 == VMEM_BUDGET_BYTES
+    # the streamed [k, bm] table tile stays within its budget at any k
+    for k in (2, KERNEL_MAX_K, 64, 1024):
+        assert stream_block_m(k) * k * 4 <= GAIN_STREAM_TILE_BYTES

@@ -7,7 +7,7 @@ import pytest
 
 from repro.kernels import ops, ref
 from repro.kernels.connectivity import connectivity_pallas, cutsize_pallas
-from repro.kernels.gain import gain_gather_pallas, gain_gather_batch_pallas
+from repro.kernels.gain import gain_stream_pallas, gain_stream_batch_pallas
 from repro.kernels.embedding_bag import embedding_bag_pallas
 
 
@@ -47,7 +47,7 @@ def test_gain_gather_sweep(n, d, m, k):
     incident = rng.integers(-1, m, size=(n, d)).astype(np.int32)
     bi = rng.normal(size=(m, k)).astype(np.float32)
     wi = rng.normal(size=(m,)).astype(np.float32)
-    got = gain_gather_pallas(jnp.asarray(incident), jnp.asarray(bi),
+    got = gain_stream_pallas(jnp.asarray(incident), jnp.asarray(bi),
                              jnp.asarray(wi))
     want = ref.gain_gather_ref(jnp.asarray(incident), jnp.asarray(bi),
                                jnp.asarray(wi))
@@ -65,7 +65,7 @@ def test_gain_gather_batch_sweep(alpha, n, d, m, k):
     incident = rng.integers(-1, m, size=(n, d)).astype(np.int32)
     bi = rng.normal(size=(alpha, m, k)).astype(np.float32)
     wi = rng.normal(size=(alpha, m)).astype(np.float32)
-    got = gain_gather_batch_pallas(jnp.asarray(incident), jnp.asarray(bi),
+    got = gain_stream_batch_pallas(jnp.asarray(incident), jnp.asarray(bi),
                                    jnp.asarray(wi))
     want = ref.gain_gather_batch_ref(jnp.asarray(incident), jnp.asarray(bi),
                                      jnp.asarray(wi))
@@ -81,10 +81,10 @@ def test_batch_kernel_matches_per_member_kernel():
     incident = rng.integers(-1, m, size=(n, d)).astype(np.int32)
     bi = rng.normal(size=(alpha, m, k)).astype(np.float32)
     wi = rng.normal(size=(alpha, m)).astype(np.float32)
-    batched = np.asarray(gain_gather_batch_pallas(
+    batched = np.asarray(gain_stream_batch_pallas(
         jnp.asarray(incident), jnp.asarray(bi), jnp.asarray(wi)))
     for a in range(alpha):
-        single = np.asarray(gain_gather_pallas(
+        single = np.asarray(gain_stream_pallas(
             jnp.asarray(incident), jnp.asarray(bi[a]), jnp.asarray(wi[a])))
         np.testing.assert_allclose(batched[a], single, rtol=1e-6, atol=1e-6)
 
@@ -115,6 +115,17 @@ def test_interpret_mode_env_override(monkeypatch):
     monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "auto")
     # this container runs on CPU -> interpreter
     assert ops.interpret_mode() is True
+
+
+def test_interpret_mode_refused_on_device_backend(monkeypatch):
+    """Forcing the interpreter on a compiled backend raises instead of
+    silently running every kernel on the host."""
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "tpu")
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
+    with pytest.raises(RuntimeError, match="REPRO_PALLAS_INTERPRET"):
+        ops.interpret_mode()
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+    assert ops.interpret_mode() is False
 
 
 @pytest.mark.parametrize("r,d,b,l,dtype,combiner", [
