@@ -1,0 +1,8 @@
+"""The device's idle share of the traced window, in percent:
+1 - (union of device-op intervals) / window, from the profiler trace."""
+
+
+def read(run):
+    if run.trace is None:
+        return None
+    return 100.0 * run.trace.idle_share
