@@ -58,6 +58,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from .. import obs
 from ..jaxcompat import shard_map
 from .hypergraph import (Hypergraph, HypergraphArrays, HierarchyArrays,
                          DeviceLevel, contract_arrays, _round_pow2,
@@ -582,12 +583,21 @@ def _attach_incident(hga: HypergraphArrays, m: int,
     deg = jnp.zeros(hga.n_pad, jnp.int32).at[hga.pin_vertex].add(
         (hga.pin_edge != hga.m_pad - 1).astype(jnp.int32))
     deg = deg.at[hga.n_pad - 1].set(0)
-    d_max = int(deg.max())  # one scalar readback, once per level
+    d_max = _read_int(deg.max())  # one scalar readback, once per level
     d_pad = max(_round_pow2(max(d_max, 1), _INCIDENCE_LANE_PAD),
                 _INCIDENCE_LANE_PAD)
     if hga.n_pad * d_pad > _INCIDENCE_MAX_EXPANSION * max(p, 1):
         return hga
     return dataclasses.replace(hga, incident=_incidence_dev(hga, d_pad))
+
+
+def _read_int(x) -> int:
+    """One device scalar read back to the host (a ``wait`` span, one
+    ``readbacks``)."""
+    with obs.span("wait"):
+        v = int(x)
+    obs.count("readbacks")
+    return v
 
 
 def device_coarsen(hg: Hypergraph, k: int, *,
@@ -629,17 +639,17 @@ def device_coarsen(hg: Hypergraph, k: int, *,
         # levels it cannot take (odd padding split, an oversized edge)
         # just fall back round-by-round
         if mesh is not None and _round_can_shard(
-                cur, mesh, int(cur.edge_sizes.max())):
+                cur, mesh, _read_int(cur.edge_sizes.max())):
             round_fn = _coarsen_round_model(mesh)
         else:
             round_fn = _coarsen_round
         coarse, cid, new_part, p_new = round_fn(
             cur, cur_part, sub, jnp.float32(sched.c_max),
             max_stride=MAX_STRIDE, max_edge_size=MAX_EDGE_SIZE)
-        n_new = int(coarse.n)
+        n_new = _read_int(coarse.n)
         if sched.stalled(n_cur, n_new):
             break
-        m_new, p_new = int(coarse.m), int(p_new)
+        m_new, p_new = _read_int(coarse.m), _read_int(p_new)
         n_pad2 = _round_pow2(n_new + 1)
         m_pad2 = _round_pow2(m_new + 1)
         p_pad2 = _round_pow2(p_new + 1)

@@ -52,6 +52,7 @@ from typing import List, Optional, Tuple
 import numpy as np
 import jax.numpy as jnp
 
+from .. import obs
 from . import dcoarsen, metrics
 from . import refine as refine_mod
 from .coarsen import Hierarchy, Level
@@ -234,10 +235,11 @@ class IncrementalState:
             e["hier"], e["hg"] = hier, hg
             return hier, "replayed"
         how = "cold" if e is None else "patched"
-        hier = dcoarsen.build_hierarchy(
-            hg, cfg.k, seed=cfg.seed, restrict_part=incumbent,
-            contraction_limit_factor=cfg.contraction_limit_factor,
-            model_shard=cfg.model_shard)
+        with obs.span("coarsen"):
+            hier = dcoarsen.build_hierarchy(
+                hg, cfg.k, seed=cfg.seed, restrict_part=incumbent,
+                contraction_limit_factor=cfg.contraction_limit_factor,
+                model_shard=cfg.model_shard)
         self._entry = dict(token=token, k_built=cfg.k, seed=cfg.seed,
                            clf=cfg.contraction_limit_factor, hier=hier,
                            hg=hg)
@@ -375,10 +377,11 @@ def incremental_partition(hg: Hypergraph, incumbent,
     if state is not None and reuse:
         hier, how = state.hierarchy_for(hg, inc0, cfg)
     else:
-        hier = dcoarsen.build_hierarchy(
-            hg, cfg.k, seed=cfg.seed, restrict_part=inc0,
-            contraction_limit_factor=cfg.contraction_limit_factor,
-            model_shard=cfg.model_shard)
+        with obs.span("coarsen"):
+            hier = dcoarsen.build_hierarchy(
+                hg, cfg.k, seed=cfg.seed, restrict_part=inc0,
+                contraction_limit_factor=cfg.contraction_limit_factor,
+                model_shard=cfg.model_shard)
         how = "cold"
     incs, buds = project_incumbent(hier, inc0, cfg.k, budget_w)
     top = hier.num_levels - 1

@@ -58,6 +58,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from .. import obs
 from ..jaxcompat import shard_map
 from .hypergraph import HypergraphArrays, _round_pow2
 from . import metrics
@@ -379,6 +380,22 @@ def _put_hga(batch_hga, npop: int, mesh, sh, model: bool):
         n=row(padded.n), m=row(padded.m))
 
 
+def _read(outs) -> Tuple[np.ndarray, ...]:
+    """Bring a dispatch's outputs to the host: the host blocks on the
+    device here (a ``wait`` span, one ``readbacks``)."""
+    with obs.span("wait"):
+        got = tuple(np.asarray(o) for o in outs)
+    obs.count("readbacks")
+    return got
+
+
+def _cuts_of(batch: InstanceBatch, parts: np.ndarray) -> np.ndarray:
+    """[I, A] f64 cuts of a host population stack, read back."""
+    return np.asarray(_read((_cutsize_instances(
+        batch.hga, jnp.asarray(parts), batch.k_pad, batch.k_live),))[0],
+        np.float64)
+
+
 def _dispatch_lp(batch: InstanceBatch, parts, cuts32, fracs, live, att,
                  path: str, model_shard: Optional[str] = None):
     """One grouped LP attempt dispatch; returns numpy
@@ -399,7 +416,7 @@ def _dispatch_lp(batch: InstanceBatch, parts, cuts32, fracs, live, att,
         out = fn(hga_p, *(put(a) for a in args), put(batch.cap),
                  put(batch.k_live), opt(batch.incumbent),
                  opt(batch.mig_budget))
-        return tuple(np.asarray(o)[:nI] for o in out)
+        return tuple(o[:nI] for o in _read(out))
     if path == "chunk":
         devs = popshard.local_devices()
         nI = parts.shape[0]
@@ -417,13 +434,14 @@ def _dispatch_lp(batch: InstanceBatch, parts, cuts32, fracs, live, att,
                     k=k, cap=put(batch.cap), k_live=put(batch.k_live),
                     incumbent=opt(batch.incumbent),
                     mig_budget=opt(batch.mig_budget)))
-            return tuple(np.concatenate([np.asarray(o[i]) for o in outs])
+            got = [_read(o) for o in outs]
+            return tuple(np.concatenate([g[i] for g in got])
                          for i in range(5))
     out = _lp_attempt_instances(batch.hga, *args, k=k, cap=batch.cap,
                                 k_live=batch.k_live,
                                 incumbent=batch.incumbent,
                                 mig_budget=batch.mig_budget)
-    return tuple(np.asarray(o) for o in out)
+    return _read(out)
 
 
 def _dispatch_fm(batch: InstanceBatch, parts, path: str,
@@ -442,8 +460,8 @@ def _dispatch_fm(batch: InstanceBatch, parts, path: str,
                  put(jnp.asarray(parts)), put(batch.cap),
                  put(batch.fm_steps), put(batch.k_live),
                  opt(batch.incumbent), opt(batch.mig_budget))
-        return (np.asarray(out[0])[:nI],
-                np.asarray(out[1])[:nI].astype(np.float64))
+        cands, cs = _read(out)
+        return cands[:nI], cs[:nI].astype(np.float64)
     if path == "chunk":
         devs = popshard.local_devices()
         nI = parts.shape[0]
@@ -461,15 +479,16 @@ def _dispatch_fm(batch: InstanceBatch, parts, path: str,
                     steps=put(batch.fm_steps), k_live=put(batch.k_live),
                     incumbent=opt(batch.incumbent),
                     mig_budget=opt(batch.mig_budget)))
-            return (np.concatenate([np.asarray(o[0]) for o in outs]),
-                    np.concatenate([np.asarray(o[1])
-                                    for o in outs]).astype(np.float64))
+            got = [_read(o) for o in outs]
+            return (np.concatenate([g[0] for g in got]),
+                    np.concatenate([g[1] for g in got]).astype(np.float64))
     out = _fm_pass_instances(batch.hga, jnp.asarray(parts), k=k,
                              cap=batch.cap, steps=batch.fm_steps,
                              k_live=batch.k_live,
                              incumbent=batch.incumbent,
                              mig_budget=batch.mig_budget)
-    return np.asarray(out[0]), np.asarray(out[1], np.float64)
+    cands, cs = _read(out)
+    return cands, np.asarray(cs, np.float64)
 
 
 def lp_refine_instances(batch: InstanceBatch, parts, max_iters: int = 24,
@@ -483,9 +502,7 @@ def lp_refine_instances(batch: InstanceBatch, parts, max_iters: int = 24,
     path = _route(shard)
     parts = np.asarray(parts, np.int32)
     nI, alpha = parts.shape[:2]
-    cuts = np.asarray(_cutsize_instances(batch.hga, jnp.asarray(parts),
-                                         batch.k_pad, batch.k_live),
-                      np.float64)
+    cuts = _cuts_of(batch, parts)
     stall = np.zeros((nI, alpha), np.int32)
     done = np.zeros((nI, alpha), bool)
     for _ in range(max_iters):
@@ -508,6 +525,8 @@ def lp_refine_instances(batch: InstanceBatch, parts, max_iters: int = 24,
             cuts = np.where(live, new_cuts.astype(np.float64), cuts)
             fracs = np.where(live, new_fracs, fracs)
             improved = improved.astype(bool) & live
+            obs.count("lp.lane_attempts", live, used[:, None])
+            obs.count("lp.lane_improved", improved)
             improved_round |= improved
             remaining = remaining - np.asarray(used, np.int64)
             live = live & ~improved
@@ -528,15 +547,16 @@ def fm_refine_instances(batch: InstanceBatch, parts,
     path = _route(shard)
     parts = np.asarray(parts, np.int32)
     nI, alpha = parts.shape[:2]
-    cuts = np.asarray(_cutsize_instances(batch.hga, jnp.asarray(parts),
-                                         batch.k_pad, batch.k_live),
-                      np.float64)
+    cuts = _cuts_of(batch, parts)
     done = np.zeros((nI, alpha), bool)
     for _ in range(max_passes):
         if done.all():
             break
         cands, cs = _dispatch_fm(batch, parts, path, model_shard)
-        take = (cs < cuts - 1e-6) & ~done
+        pending = ~done
+        take = (cs < cuts - 1e-6) & pending
+        obs.count("fm.lane_passes", pending)
+        obs.count("fm.lane_accepted", take)
         parts = np.where(take[:, :, None], cands, parts)
         cuts = np.where(take, cs, cuts)
         done |= ~take
@@ -553,20 +573,23 @@ def refine_instances(batch: InstanceBatch, parts,
     instance; the FM tier runs on the sub-batch of instances whose true
     n is within ``fm_node_limit`` (sliced out and written back), exactly
     the per-instance decision the solo driver makes."""
-    parts, cuts = lp_refine_instances(batch, parts, max_iters=max_iters,
-                                      patience=patience, shard=shard,
-                                      model_shard=model_shard)
+    with obs.span("refine.lp"):
+        parts, cuts = lp_refine_instances(batch, parts, max_iters=max_iters,
+                                          patience=patience, shard=shard,
+                                          model_shard=model_shard)
     fm_idx = [i for i, n in enumerate(batch.ns) if n <= fm_node_limit]
     if fm_idx:
-        if len(fm_idx) == batch.n_instances:
-            parts, cuts = fm_refine_instances(batch, parts, shard=shard,
-                                              model_shard=model_shard)
-        else:
-            sub = _take_i(batch, fm_idx)
-            sp, sc = fm_refine_instances(sub, parts[fm_idx], shard=shard,
-                                         model_shard=model_shard)
-            parts[fm_idx] = sp
-            cuts[fm_idx] = sc
+        with obs.span("refine.fm"):
+            if len(fm_idx) == batch.n_instances:
+                parts, cuts = fm_refine_instances(batch, parts, shard=shard,
+                                                  model_shard=model_shard)
+            else:
+                sub = _take_i(batch, fm_idx)
+                sp, sc = fm_refine_instances(sub, parts[fm_idx],
+                                             shard=shard,
+                                             model_shard=model_shard)
+                parts[fm_idx] = sp
+                cuts[fm_idx] = sc
     return parts, cuts
 
 
@@ -592,7 +615,7 @@ def refine_grouped(entries, grid: Optional[Sequence[int]] = None,
     for i, e in enumerate(entries):
         groups.setdefault(group_key(e[0], e[2], grid), []).append(i)
     out: List = [None] * len(entries)
-    for idx in groups.values():
+    for (n_bucket, k_bucket_), idx in groups.items():
         hgas = [entries[i][0] for i in idx]
         ks = [entries[i][2] for i in idx]
         epss = [entries[i][3] for i in idx]
@@ -602,13 +625,17 @@ def refine_grouped(entries, grid: Optional[Sequence[int]] = None,
                for i in idx]
         if all(x is None for x in incs):
             incs = mbs = None
-        batch = stack_instances(hgas, ks, epss, grid=grid,
-                                incumbents=incs, mig_budgets=mbs)
-        parts = stack_parts([entries[i][1] for i in idx], batch.n_pad)
-        rp, rc = refine_instances(batch, parts,
-                                  fm_node_limit=fm_node_limit,
-                                  max_iters=max_iters, patience=patience,
-                                  shard=shard, model_shard=model_shard)
+        with obs.span("refine", n=n_bucket, k=k_bucket_):
+            with obs.span("refine.stack"):
+                batch = stack_instances(hgas, ks, epss, grid=grid,
+                                        incumbents=incs, mig_budgets=mbs)
+                parts = stack_parts([entries[i][1] for i in idx],
+                                    batch.n_pad)
+            rp, rc = refine_instances(batch, parts,
+                                      fm_node_limit=fm_node_limit,
+                                      max_iters=max_iters,
+                                      patience=patience, shard=shard,
+                                      model_shard=model_shard)
         for j, i in enumerate(idx):
             out[i] = (rp[j][:, : batch.orig_n_pads[j]], rc[j])
     return out

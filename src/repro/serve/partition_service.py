@@ -68,6 +68,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.core.hypergraph import Hypergraph
 from repro.core.impart import ImpartConfig, impart_partition
 from repro.core.dcoarsen import build_hierarchy
@@ -476,10 +477,11 @@ class PartitionService:
         if req.incumbent is not None:
             icfg = self._icfg_for(req, seed_bump=seed_bump)
             inc0 = np.asarray(req.incumbent, np.int32)
-            hier = build_hierarchy(
-                req.hg, icfg.k, seed=icfg.seed, restrict_part=inc0,
-                contraction_limit_factor=icfg.contraction_limit_factor,
-                model_shard=icfg.model_shard)
+            with obs.span("coarsen", req=req.name):
+                hier = build_hierarchy(
+                    req.hg, icfg.k, seed=icfg.seed, restrict_part=inc0,
+                    contraction_limit_factor=icfg.contraction_limit_factor,
+                    model_shard=icfg.model_shard)
             budget_w = (np.inf if icfg.migration_frac is None else
                         float(icfg.migration_frac)
                         * float(np.sum(req.hg.vertex_weights)))
@@ -490,15 +492,17 @@ class PartitionService:
             slot.incs, slot.buds = incs, buds
             slot.best_cut = None  # baseline set by the first dispatch
         else:
-            hier = build_hierarchy(
-                req.hg, cfg.k, seed=cfg.seed,
-                contraction_limit_factor=cfg.contraction_limit_factor,
-                model_shard=cfg.model_shard)
+            with obs.span("coarsen", req=req.name):
+                hier = build_hierarchy(
+                    req.hg, cfg.k, seed=cfg.seed,
+                    contraction_limit_factor=cfg.contraction_limit_factor,
+                    model_shard=cfg.model_shard)
             num = hier.num_levels
-            parts, init_cuts = initial_partition_population(
-                hier.level_host(num - 1), cfg.k, cfg.eps,
-                seeds=[cfg.seed * 101 + i for i in range(cfg.alpha)],
-                tries_per_strategy=1, hga=hier.level_arrays(num - 1))
+            with obs.span("initial", req=req.name):
+                parts, init_cuts = initial_partition_population(
+                    hier.level_host(num - 1), cfg.k, cfg.eps,
+                    seeds=[cfg.seed * 101 + i for i in range(cfg.alpha)],
+                    tries_per_strategy=1, hga=hier.level_arrays(num - 1))
             slot.incs, slot.buds = None, None
             slot.best_cut = float(np.min(np.asarray(init_cuts)))
         slot.request, slot.cfg, slot.hier = req, cfg, hier
@@ -763,6 +767,10 @@ class PartitionService:
         quarantine, advance/finish slots, snapshot.  Returns the number
         of requests that reached a terminal state this tick."""
         self.tick += 1
+        with obs.span("service.tick", tick=self.tick):
+            return self._tick()
+
+    def _tick(self) -> int:
         t_tick = time.perf_counter()
         events = (self.fault_plan.events_for(self.tick)
                   if self.fault_plan else [])
@@ -790,7 +798,8 @@ class PartitionService:
         entries = []
         for s in dispatch:
             if s.need_project:
-                s.parts = s.hier.project_pop(s.parts, s.li + 1)
+                with obs.span("project", req=s.request.name):
+                    s.parts = s.hier.project_pop(s.parts, s.li + 1)
                 s.need_project = False
             if s.incs is not None:
                 entries.append((s.hier.level_arrays(s.li), s.parts,
