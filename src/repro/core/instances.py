@@ -273,29 +273,31 @@ def _lp_attempt_instances_mesh(mesh, k: int, model: bool = False):
 
 def _fm_pass_instances_impl(hga, parts, k: int, cap, steps, k_live,
                             incumbent=None, mig_budget=None,
-                            pin_axis=None):
+                            pin_axis=None, assemble: str = "segsum"):
     def one(h, p, cp, st, kl, inc, mb):
         return refine_mod._fm_pass_population_impl(h, p, k, cp, st,
                                                    k_live=kl,
                                                    incumbent=inc,
                                                    mig_budget=mb,
-                                                   pin_axis=pin_axis)
+                                                   pin_axis=pin_axis,
+                                                   assemble=assemble)
     return jax.vmap(one)(hga, parts, cap, steps, k_live, incumbent,
                          mig_budget)
 
 
-_fm_pass_instances = partial(jax.jit, static_argnames=("k",))(
+_fm_pass_instances = partial(jax.jit, static_argnames=("k", "assemble"))(
     _fm_pass_instances_impl)
 
 
 @lru_cache(maxsize=32)
-def _fm_pass_instances_mesh(mesh, k: int, model: bool = False):
+def _fm_pass_instances_mesh(mesh, k: int, model: bool = False,
+                            assemble: str = "segsum"):
     def body(hga, parts, cap, steps, k_live, incumbent, mig_budget):
         return _fm_pass_instances_impl(hga, parts, k, cap, steps, k_live,
                                        incumbent=incumbent,
                                        mig_budget=mig_budget,
                                        pin_axis="model" if model
-                                       else None)
+                                       else None, assemble=assemble)
 
     fn = shard_map(body, mesh,
                    in_specs=(_hga_instance_specs(model),)
@@ -444,8 +446,20 @@ def _dispatch_lp(batch: InstanceBatch, parts, cuts32, fracs, live, att,
     return _read(out)
 
 
+def _fm_route(batch: InstanceBatch, path: str,
+              model_shard: Optional[str] = None) -> str:
+    """The FM gain route of a stacked bucket's dispatches (see
+    ``refine.fm_gain_route``), sized for all of its instances."""
+    model = (path == "mesh"
+             and _model_active(batch, popshard.pop_mesh(), model_shard))
+    return refine_mod.fm_gain_route(
+        batch.n_pad, int(batch.hga.edge_weights.shape[1]),
+        lanes=batch.n_instances, pin_axis="model" if model else None)
+
+
 def _dispatch_fm(batch: InstanceBatch, parts, path: str,
-                 model_shard: Optional[str] = None):
+                 model_shard: Optional[str] = None,
+                 assemble: str = "segsum"):
     k = batch.k_pad
     if path == "mesh":
         mesh = popshard.pop_mesh()
@@ -455,7 +469,7 @@ def _dispatch_fm(batch: InstanceBatch, parts, path: str,
         model = _model_active(batch, mesh, model_shard)
         put = lambda x: jax.device_put(_pad_i(x, npop), sh)
         opt = lambda x: None if x is None else put(x)
-        fn = _fm_pass_instances_mesh(mesh, k, model)
+        fn = _fm_pass_instances_mesh(mesh, k, model, assemble)
         out = fn(_put_hga(batch.hga, npop, mesh, sh, model),
                  put(jnp.asarray(parts)), put(batch.cap),
                  put(batch.fm_steps), put(batch.k_live),
@@ -478,7 +492,7 @@ def _dispatch_fm(batch: InstanceBatch, parts, path: str,
                     put(jnp.asarray(parts)), k=k, cap=put(batch.cap),
                     steps=put(batch.fm_steps), k_live=put(batch.k_live),
                     incumbent=opt(batch.incumbent),
-                    mig_budget=opt(batch.mig_budget)))
+                    mig_budget=opt(batch.mig_budget), assemble=assemble))
             got = [_read(o) for o in outs]
             return (np.concatenate([g[0] for g in got]),
                     np.concatenate([g[1] for g in got]).astype(np.float64))
@@ -486,7 +500,7 @@ def _dispatch_fm(batch: InstanceBatch, parts, path: str,
                              cap=batch.cap, steps=batch.fm_steps,
                              k_live=batch.k_live,
                              incumbent=batch.incumbent,
-                             mig_budget=batch.mig_budget)
+                             mig_budget=batch.mig_budget, assemble=assemble)
     cands, cs = _read(out)
     return cands, np.asarray(cs, np.float64)
 
@@ -545,6 +559,7 @@ def fm_refine_instances(batch: InstanceBatch, parts,
     rejected candidate deterministically), so per-lane acceptance
     decisions match the compacting solo loop exactly."""
     path = _route(shard)
+    assemble = _fm_route(batch, path, model_shard)
     parts = np.asarray(parts, np.int32)
     nI, alpha = parts.shape[:2]
     cuts = _cuts_of(batch, parts)
@@ -552,10 +567,12 @@ def fm_refine_instances(batch: InstanceBatch, parts,
     for _ in range(max_passes):
         if done.all():
             break
-        cands, cs = _dispatch_fm(batch, parts, path, model_shard)
+        cands, cs = _dispatch_fm(batch, parts, path, model_shard, assemble)
         pending = ~done
         take = (cs < cuts - 1e-6) & pending
         obs.count("fm.lane_passes", pending)
+        if assemble == "dense":
+            obs.count("fm.dense_lanes", pending)
         obs.count("fm.lane_accepted", take)
         parts = np.where(take[:, :, None], cands, parts)
         cuts = np.where(take, cs, cuts)

@@ -163,10 +163,51 @@ def _gain_compact(hga: HypergraphArrays, phi: jnp.ndarray, k: int,
     return g - l[:, None]
 
 
+def pin_multiplicity(hga: HypergraphArrays) -> jnp.ndarray:
+    """H [n_pad, m_pad] bf16, the level's dense incidence: H[v, e] is the
+    number of pins of v in e, built with one scatter of the pins.  Exact
+    while a vertex holds at most 256 pins of one net (bf16 holds every
+    integer up to 256).  Ghost pins pile onto (ghost vertex, ghost edge),
+    where the count may round: that edge's weight and size are 0, so
+    every gain term it meets is 0."""
+    return jnp.zeros((hga.n_pad, hga.m_pad), jnp.bfloat16).at[
+        hga.pin_vertex, hga.pin_edge].add(jnp.bfloat16(1))
+
+
+def _gain_dense(hga: HypergraphArrays, mult: jnp.ndarray, phi: jnp.ndarray,
+                k: int) -> jnp.ndarray:
+    """[n_pad, k] gains as one contraction of the dense incidence ``mult``
+    (``pin_multiplicity``) with the per-edge terms: no scatter, and under
+    a vmap over members with ``mult`` unbatched, one matmul that reads
+    ``mult`` once for all of them.  The own-block entries are left as
+    they come (callers mask them).
+
+    Exactness: the f32 terms are split into three bf16 pieces that sum
+    back to them exactly, and each bf16 x bf16 product is exact in the
+    f32 accumulator.  With integer edge weights (every instance the
+    engines take, see ``_gain_segsum``) and per-vertex sums below 2**24,
+    every partial sum is an integer held exactly, so the gains are
+    bit-equal to ``_gain_segsum``."""
+    becomes_internal, was_internal = _edge_gain_terms(hga, phi)
+    x = jnp.concatenate([becomes_internal.T, was_internal[None, :]])
+    hi = x.astype(jnp.bfloat16)                         # [k+1, m_pad]
+    rest = x - hi.astype(jnp.float32)
+    mid = rest.astype(jnp.bfloat16)
+    lo = (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    # edges minor on both sides: under the members' vmap the batched side
+    # folds into the rows of one [members * 3(k+1), m_pad] x [m_pad, n_pad]
+    # matmul
+    g = jax.lax.dot_general(jnp.concatenate([hi, mid, lo]), mult,
+                            (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    g = g[:k + 1] + g[k + 1:2 * (k + 1)] + g[2 * (k + 1):]  # [k+1, n_pad]
+    return (g[:k] - g[k:]).T
+
+
 def _resolve_gain_path(hga: HypergraphArrays, k: int, assemble: str) -> str:
     """Static (trace-time) path choice: "auto" consults the ops
-    dispatcher by (k, backend); a concrete path name forces it
-    (the FM move loop pins "segsum" — see ``refine._fm_pass_impl``)."""
+    dispatcher by (k, backend); a concrete path name forces it.  The FM
+    move loop assembles its own gains (``refine._fm_pass_impl``)."""
     from repro.kernels import ops
     if assemble == "auto":
         return ops.gain_path(k, incidence=hga.incident is not None)

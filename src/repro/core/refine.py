@@ -7,6 +7,8 @@
 * ``fm_refine`` — classic one-move-at-a-time FM with negative-gain
   hill-climbing and best-prefix rollback, expressed as a ``lax.scan``.
   Used on coarse levels (small n) where move quality matters most.
+  Each move's gains come from segment-sums over the pins or from one
+  contraction of the level's dense incidence (``fm_gain_route``).
 
 The population LP tier is device-resident: the whole 5-attempt
 frac-halving acceptance loop of a round runs inside one jitted
@@ -37,6 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from .. import obs
 from ..jaxcompat import shard_map
 from .hypergraph import HypergraphArrays
 from . import metrics
@@ -558,12 +561,58 @@ def lp_refine_population(hga: HypergraphArrays, parts, k: int, eps: float,
 # --------------------------------------------------------------------------
 # sequential FM (scan) for coarse levels
 # --------------------------------------------------------------------------
+#: Share of one device's memory that the dense FM route's incidences (one
+#: [n_pad, m_pad] bf16 matrix per instance of a dispatch) may take; a
+#: dispatch that would need more keeps the segment-sum route.
+FM_DENSE_MEM_SHARE = 1 / 8
+#: Device memory assumed where the backend reports no limit (one TPU v5e).
+DEVICE_BYTES_FALLBACK = 16 * 2**30
+
+
+@lru_cache(maxsize=1)
+def _device_bytes() -> int:
+    stats = jax.local_devices()[0].memory_stats() or {}
+    return int(stats.get("bytes_limit", DEVICE_BYTES_FALLBACK))
+
+
+def fm_dense_budget() -> int:
+    """Bytes the dense FM route's incidences may take on one device."""
+    return int(_device_bytes() * FM_DENSE_MEM_SHARE)
+
+
+def fm_gain_route(n_pad: int, m_pad: int, lanes: int = 1,
+                  pin_axis: str | None = None,
+                  backend: str | None = None) -> str:
+    """How an FM pass dispatch assembles its per-move gains: "dense" (the
+    level's dense incidence contracted on the matrix unit, no scatter in
+    the move loop) or "segsum" (per-pin gathers and segment-sums).
+
+    Decided from what the dispatch can observe, before it is traced:
+    "segsum" on the CPU (a dense contraction per move step costs more
+    there than the tiny coarse-level scatters), with pin tables sharded
+    over ``pin_axis`` (that path exists for structures too large to
+    replicate, where a dense incidence is the wrong tool), and when the
+    ``lanes`` incidences of [n_pad, m_pad] bf16 the dispatch would hold
+    exceed ``fm_dense_budget()``; "dense" otherwise.  ``backend``
+    defaults to JAX's.  Both routes return the same partitions and
+    cuts."""
+    if pin_axis is not None:
+        return "segsum"
+    if (backend or jax.default_backend()) == "cpu":
+        return "segsum"
+    if lanes * n_pad * m_pad * 2 > fm_dense_budget():
+        return "segsum"
+    return "dense"
+
+
 def _fm_pass_impl(hga: HypergraphArrays, part: jnp.ndarray, k: int,
                   cap: jnp.ndarray, steps: int,
                   k_live: jnp.ndarray | None = None,
                   incumbent: jnp.ndarray | None = None,
                   mig_budget: jnp.ndarray | None = None,
-                  pin_axis: str | None = None
+                  pin_axis: str | None = None,
+                  assemble: str = "segsum",
+                  mult: jnp.ndarray | None = None
                   ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """One FM pass: up to ``steps`` single moves (negative gains allowed),
     returns the best prefix (partition + its cut).
@@ -593,9 +642,21 @@ def _fm_pass_impl(hga: HypergraphArrays, part: jnp.ndarray, k: int,
     as psum'd int32/integer-f32 partials; every carried state leaf is
     [n_pad]/[m_pad]-indexed and identical on all shards, so the move
     sequence is bit-identical to the replicated pass.
+
+    ``assemble`` (static, ``fm_gain_route``) picks how each move's gains
+    and pin counts are assembled: "segsum" gathers and segment-sums over
+    every pin per move; "dense" contracts the level's dense incidence
+    ``mult`` (``metrics.pin_multiplicity``, built here when not given)
+    with the edge terms and reads the moved vertex's pin counts as one
+    row of it, so the move loop holds no scatter.  Both give the same
+    integers (``metrics._gain_dense``), so the move sequence is
+    bit-identical.
     """
+    if assemble == "dense" and mult is None:
+        mult = metrics.pin_multiplicity(hga)
     n_pad = hga.n_pad
-    valid = (jnp.arange(n_pad) < hga.n) & (hga.vertex_weights > 0)
+    rows = jnp.arange(n_pad, dtype=jnp.int32)
+    valid = (rows < hga.n) & (hga.vertex_weights > 0)
     phi0 = metrics.pins_in_block(hga, part, k, pin_axis=pin_axis)
     bw0 = metrics.block_weights(hga, part, k)
     cut0 = metrics.cutsize(hga, part, k, pin_axis=pin_axis)
@@ -607,14 +668,15 @@ def _fm_pass_impl(hga: HypergraphArrays, part: jnp.ndarray, k: int,
     def body(carry):
         (part, phi, bw, locked, cur_cut, best_cut, best_part, mig_w,
          t, _) = carry
-        # FM pins the segsum path: this body is vmapped by the population
-        # pass, so batching must stay a plain XLA transform (never a
-        # pallas_call), and FM only runs on coarse levels whose tiny pin
-        # counts make the [P, k] segment-sum cheaper per move step than
-        # the compact path's fixed extract/scatter overhead
-        gains = metrics.gain_matrix(hga, part, k, phi=phi,
-                                    assemble="segsum",
-                                    pin_axis=pin_axis)        # [n_pad, k]
+        # the gains come from plain XLA (this body is vmapped by the
+        # population pass, so never a pallas_call); the own-block entries
+        # are masked in ``score`` below
+        if assemble == "dense":
+            gains = metrics._gain_dense(hga, mult, phi, k)     # [n_pad, k]
+        else:
+            gains = metrics.gain_matrix(hga, part, k, phi=phi,
+                                        assemble="segsum",
+                                        pin_axis=pin_axis)    # [n_pad, k]
         own = jax.nn.one_hot(part, k, dtype=bool)
         feasible = (bw[None, :] + hga.vertex_weights[:, None]) <= cap + 1e-6
         score = jnp.where(own | ~feasible, NEG, gains)
@@ -637,22 +699,25 @@ def _fm_pass_impl(hga: HypergraphArrays, part: jnp.ndarray, k: int,
         do = g > NEG / 2  # any feasible move at all?
 
         b = part[v]
-        d = jax.ops.segment_sum(
-            (hga.pin_vertex == v).astype(jnp.int32), hga.pin_edge,
-            num_segments=hga.m_pad)                            # [m_pad]
-        if pin_axis is not None:
-            d = jax.lax.psum(d, pin_axis)  # v's pins span shards
+        if assemble == "dense":
+            d = mult[v].astype(jnp.int32)                      # [m_pad]
+        else:
+            d = jax.ops.segment_sum(
+                (hga.pin_vertex == v).astype(jnp.int32), hga.pin_edge,
+                num_segments=hga.m_pad)                        # [m_pad]
+            if pin_axis is not None:
+                d = jax.lax.psum(d, pin_axis)  # v's pins span shards
         delta = (jax.nn.one_hot(j, k, dtype=phi.dtype)
                  - jax.nn.one_hot(b, k, dtype=phi.dtype))      # [k]
         phi_new = phi + d[:, None] * delta[None, :]
         bw_new = bw + hga.vertex_weights[v] * delta
-        part_new = part.at[v].set(j)
+        moved = do & (rows == v)                               # [n_pad]
         cut_new = cur_cut - g
 
-        part = jnp.where(do, part_new, part)
+        part = jnp.where(moved, j, part)
         phi = jnp.where(do, phi_new, phi)
         bw = jnp.where(do, bw_new, bw)
-        locked = locked.at[v].set(jnp.where(do, True, locked[v]))
+        locked = locked | moved
         cur_cut = jnp.where(do, cut_new, cur_cut)
         if incumbent is not None:
             mig_w = jnp.where(do, mig_w + delta_mig[v, j], mig_w)
@@ -673,7 +738,8 @@ def _fm_pass_impl(hga: HypergraphArrays, part: jnp.ndarray, k: int,
     return out[6], out[5]
 
 
-_fm_pass = jax.jit(_fm_pass_impl, static_argnames=("k", "steps"))
+_fm_pass = jax.jit(_fm_pass_impl,
+                   static_argnames=("k", "steps", "assemble"))
 
 
 def _fm_pass_population_impl(hga: HypergraphArrays, parts: jnp.ndarray,
@@ -682,33 +748,34 @@ def _fm_pass_population_impl(hga: HypergraphArrays, parts: jnp.ndarray,
                              k_live: jnp.ndarray | None = None,
                              incumbent: jnp.ndarray | None = None,
                              mig_budget: jnp.ndarray | None = None,
-                             pin_axis: str | None = None
+                             pin_axis: str | None = None,
+                             assemble: str = "segsum"
                              ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    # the dense incidence is built once from the shared structure, outside
+    # the members' vmap, so every member's contraction reads the one copy
+    mult = (metrics.pin_multiplicity(hga) if assemble == "dense"
+            else None)
+    fm = partial(_fm_pass_impl, k=k, cap=cap, steps=steps, k_live=k_live,
+                 incumbent=incumbent, mig_budget=mig_budget,
+                 pin_axis=pin_axis, assemble=assemble, mult=mult)
     if edge_weights_pop is None:
-        return jax.vmap(
-            lambda p: _fm_pass_impl(hga, p, k, cap, steps,
-                                    k_live=k_live, incumbent=incumbent,
-                                    mig_budget=mig_budget,
-                                    pin_axis=pin_axis))(parts)
+        return jax.vmap(lambda p: fm(hga, p))(parts)
     return jax.vmap(
-        lambda p, ew: _fm_pass_impl(metrics.member_arrays(hga, ew), p, k,
-                                    cap, steps, k_live=k_live,
-                                    incumbent=incumbent,
-                                    mig_budget=mig_budget,
-                                    pin_axis=pin_axis))(
-                                        parts, edge_weights_pop)
+        lambda p, ew: fm(metrics.member_arrays(hga, ew), p))(
+            parts, edge_weights_pop)
 
 
 #: One FM pass for all members: a single [alpha]-batched move scan
 #: instead of alpha sequential scans.  With ``edge_weights_pop`` each
 #: member's lane runs on its own edge-weight row (shared structure).
-_fm_pass_population = partial(jax.jit, static_argnames=("k", "steps"))(
-    _fm_pass_population_impl)
+_fm_pass_population = partial(
+    jax.jit, static_argnames=("k", "steps", "assemble"))(
+        _fm_pass_population_impl)
 
 
 @lru_cache(maxsize=32)
 def _fm_pass_population_mesh(mesh, k: int, steps: int,
-                             model: bool = False):
+                             model: bool = False, assemble: str = "segsum"):
     """The batched FM pass shard_map'd over the ("pop", "model") mesh
     (DESIGN.md §11): structure replicated, member rows sharded over
     "pop".  FM lanes are fully row-independent (no collective needed);
@@ -722,7 +789,7 @@ def _fm_pass_population_mesh(mesh, k: int, steps: int,
         return _fm_pass_population_impl(
             hga, parts, k, cap, steps, edge_weights_pop=ew_pop,
             incumbent=incumbent, mig_budget=mig_budget,
-            pin_axis="model" if model else None)
+            pin_axis="model" if model else None, assemble=assemble)
 
     fn = shard_map(body, mesh,
                    in_specs=(_hga_specs(model), P("pop"), P(), P("pop"),
@@ -740,8 +807,9 @@ def fm_refine(hga: HypergraphArrays, part: np.ndarray, k: int, eps: float,
     cut = float(metrics.cutsize_jit(hga, part, k))
     # shape-derived so all pow2-bucketed levels share one compilation
     steps = step_budget or int(min(hga.n_pad, 1024))
+    assemble = fm_gain_route(hga.n_pad, hga.m_pad)
     for _ in range(max_passes):
-        cand, c = _fm_pass(hga, part, k, cap, steps)
+        cand, c = _fm_pass(hga, part, k, cap, steps, assemble=assemble)
         c = float(c)
         if c < cut - 1e-6:
             part, cut = cand, c
@@ -837,6 +905,10 @@ def fm_refine_population(hga: HypergraphArrays, parts, k: int, eps: float,
 
     ``incumbent`` [n] + ``mig_budget``: bounded migration (DESIGN.md
     §14), enforced move-by-move inside every member's pass.
+
+    The gain route (``fm_gain_route``) is resolved once per call;
+    ``repro.obs`` counts the lane-passes, the accepted ones and those
+    on the dense route.
     """
     cap = _cap_for(hga, k, eps)
     parts = np.array(pad_parts(parts, hga.n_pad))  # writable host copy
@@ -863,18 +935,26 @@ def fm_refine_population(hga: HypergraphArrays, parts, k: int, eps: float,
         inc_d = ([jax.device_put(inc, d) for d in devs]
                  if inc is not None else [None] * len(devs))
     mesh_fn = None
+    model = False
     if path == "mesh":
         mesh, npop, pop_sh, hga_m, cap_m, model = _mesh_dispatch(
             hga, k, eps, model_shard)
-        mesh_fn = _fm_pass_population_mesh(mesh, k, steps, model)
         if inc is not None:
             inc = jax.device_put(inc, popshard.replicated(mesh))
     else:
         popshard.enforce_structure_budget(hga, 1)
+    # members share one incidence per device (structure replicated)
+    assemble = fm_gain_route(hga.n_pad, hga.m_pad,
+                             pin_axis="model" if model else None)
+    if path == "mesh":
+        mesh_fn = _fm_pass_population_mesh(mesh, k, steps, model, assemble)
     for _ in range(max_passes):
         idx = np.nonzero(~done)[0]  # compact: finished members drop out
         if len(idx) == 0:
             break
+        obs.count("fm.lane_passes", len(idx))
+        if assemble == "dense":
+            obs.count("fm.dense_lanes", len(idx))
         sub = parts[idx]
         sub_ew = (edge_weights_pop[idx]
                   if edge_weights_pop is not None else None)
@@ -902,7 +982,7 @@ def fm_refine_population(hga: HypergraphArrays, parts, k: int, eps: float,
                 outs.append(_fm_pass_population(
                     hga_d[di], chunk, k, cap_d[di], steps,
                     edge_weights_pop=ew_chunk, incumbent=inc_d[di],
-                    mig_budget=mb))
+                    mig_budget=mb, assemble=assemble))
             cands = np.concatenate([np.asarray(o[0]) for o in outs])
             cs = np.concatenate(
                 [np.asarray(o[1]) for o in outs]).astype(np.float64)
@@ -911,10 +991,11 @@ def fm_refine_population(hga: HypergraphArrays, parts, k: int, eps: float,
                 hga, jnp.asarray(sub), k, cap, steps,
                 edge_weights_pop=None if sub_ew is None
                 else jnp.asarray(sub_ew), incumbent=inc,
-                mig_budget=mb)
+                mig_budget=mb, assemble=assemble)
             cands = np.asarray(cands)
             cs = np.asarray(cs, np.float64)
         take = cs < cuts[idx] - 1e-6
+        obs.count("fm.lane_accepted", take)
         if take.any():
             tidx = idx[take]
             parts[tidx] = cands[take]
